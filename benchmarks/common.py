@@ -17,48 +17,9 @@ $98.32/h H100-cluster price.
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Dict, Optional, Sequence, Tuple
 
-_JAX_CONFIGURED = False
-
-
-def configure_jax(cache_dir: Optional[str] = None) -> str:
-    """Dispatch hygiene for the JAX-backed engines (vector simulator,
-    batched forecaster), applied *before* first device use.
-
-    Pins the XLA host platform to one device (we vectorize with vmap,
-    not pmap — extra host devices just split the CPU) and turns on the
-    persistent compilation cache so a fresh benchmark process starts
-    from compiled kernels instead of re-tracing + re-compiling the
-    scan: BENCH_sim.json records the cold/warm split this buys.
-    Returns the cache directory in use.  Idempotent; a no-op for the
-    XLA flags if the backend is already initialized.
-    """
-    global _JAX_CONFIGURED
-    cache = cache_dir or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), ".jax_cache"))
-    if _JAX_CONFIGURED:
-        return cache
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=1").strip()
-    os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "2")
-    import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache)
-        # cache everything: the scan kernel is cheap to serialize and
-        # the whole point is skipping its ~1.5 s XLA compile
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:       # older jax: flags still applied
-        pass
-    _JAX_CONFIGURED = True
-    return cache
-
+from repro.jaxconfig import configure_jax
 
 configure_jax()
 

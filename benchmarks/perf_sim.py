@@ -242,7 +242,7 @@ def time_vector_simulation(trace, stack_spec, name: str,
     is the number the ≥20× contract in docs/PERF.md is written against,
     because sweeps always run batched.
     """
-    from benchmarks.common import configure_jax
+    from repro.jaxconfig import configure_jax
     cache = configure_jax()
     from repro.api import build_stack
     from repro.sim.vector import VectorBatch

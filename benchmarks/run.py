@@ -4,8 +4,10 @@ Prints ``name,value,derived`` CSV.  ``--quick`` shrinks traces for CI;
 ``--smoke`` runs a <60 s strategy sweep over a tiny trace through the
 declarative experiment runner — enough to catch control-plane
 regressions without the full workloads (wired into scripts/check.sh).
-``--jobs N`` fans variants out over N worker processes (default: CPU
-count); ``--out PATH`` persists the smoke sweep's JSON result artifact.
+``--jobs N`` fans event-loop variants out over N worker processes
+(default: the CPU count on a CPU backend, one process on an
+accelerator); ``--out PATH`` persists the smoke sweep's JSON result
+artifact.
 """
 from __future__ import annotations
 
@@ -196,8 +198,9 @@ def main(argv=None) -> int:
                     help="7-strategy x 4-scenario x 3-seed simulated "
                          "week (minutes on --engine vector)")
     ap.add_argument("--jobs", type=int, default=None, metavar="N",
-                    help="worker processes for experiment sweeps "
-                         "(default: CPU count)")
+                    help="worker processes for event-loop sweeps "
+                         "(default: CPU count; always 1 on an "
+                         "accelerator)")
     ap.add_argument("--out", default=None, metavar="RESULTS.json",
                     help="write the smoke sweep's result artifact here")
     ap.add_argument("--only", default=None,
@@ -214,7 +217,7 @@ def main(argv=None) -> int:
                          "boundary_s_mean regresses >2x vs this "
                          "committed file")
     args = ap.parse_args(argv)
-    jobs = args.jobs if args.jobs else (os.cpu_count() or 1)
+    jobs = args.jobs
     if args.week:
         return week(engine=args.engine, jobs=jobs, quick=args.quick,
                     out=args.out, bench_out=args.bench_out,

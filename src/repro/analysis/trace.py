@@ -8,9 +8,9 @@ jitted computations — the vector-engine segment runner
 sees:
 
 - **T1** no host callbacks (``pure_callback`` / ``io_callback`` /
-  ``debug_callback`` / infeed / outfeed) inside ``lax.scan`` bodies —
-  one callback per bucket would serialize the whole scan on host
-  round-trips;
+  ``debug_callback`` / ``debug_print`` / infeed / outfeed) inside
+  ``lax.scan`` bodies — one callback per bucket would serialize the
+  whole scan on host round-trips;
 - **T2** dtype stability: tracing under ``enable_x64`` must produce no
   non-weak float64 values.  A weak-typed f64 is a bare Python literal
   (erased by promotion against the f32 state and lowered f32 with x64
@@ -29,7 +29,7 @@ sees:
 
 Budget: canonical shapes are tiny (1 model × 2 regions, 2-bucket
 segments, (2, 16) fit batches) and compilation reuses the persistent
-XLA cache from ``benchmarks.common.configure_jax`` when available, so
+XLA cache from ``repro.jaxconfig.configure_jax``, so
 the whole tier stays well under the 60 s check.sh budget.
 
 Run via ``python -m repro.analysis --trace`` or programmatically::
@@ -42,12 +42,12 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
-import os
 import time
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from repro.analysis.core import Violation
+from repro.jaxconfig import configure_jax
 
 TRACE_RULES = ("T1", "T2", "T3", "T4")
 
@@ -59,50 +59,23 @@ TRACE_RULE_DOCS = {
 }
 
 _HOST_CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "callback",
-    "infeed", "outfeed",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "callback", "infeed", "outfeed",
 })
 
 _CARRYING_PRIMS = frozenset({"scan", "while"})
 
 
-def _configure_jax() -> None:
-    """Single-device host platform + the repo's persistent compilation
-    cache.  Reuses benchmarks.common.configure_jax when importable (the
-    normal check.sh path, cwd = repo root); otherwise applies the same
-    settings inline so the tier also runs from arbitrary cwds."""
-    try:
-        from benchmarks.common import configure_jax
-        configure_jax()
-        return
-    except ImportError:
-        pass
-    os.environ.setdefault("XLA_FLAGS",
-                          "--xla_force_host_platform_device_count=1")
-    import jax
-    cache = Path.cwd() / ".jax_cache"
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass  # older jax: run without the persistent cache
-
-
 # --------------------------------------------------------------- jaxpr walks
 def _sub_jaxprs(eqn):
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     for p in eqn.params.values():
-        if isinstance(p, jax.core.ClosedJaxpr):
-            yield p.jaxpr
-        elif isinstance(p, jax.core.Jaxpr):
-            yield p
-        elif isinstance(p, (tuple, list)):
-            for q in p:
-                if isinstance(q, jax.core.ClosedJaxpr):
-                    yield q.jaxpr
-                elif isinstance(q, jax.core.Jaxpr):
-                    yield q
+        for q in (p if isinstance(p, (tuple, list)) else (p,)):
+            if isinstance(q, ClosedJaxpr):
+                yield q.jaxpr
+            elif isinstance(q, Jaxpr):
+                yield q
 
 
 def iter_eqns(jaxpr, scan_depth: int = 0):
@@ -127,7 +100,7 @@ def host_callbacks_in_scan(closed) -> List[str]:
 
 def float64_leaks(closed) -> List[str]:
     """T2 core: non-weak float64 outvars anywhere in the jaxpr.  Trace
-    the target under ``jax.experimental.enable_x64()`` first — with x64
+    the target under ``jax.enable_x64(True)`` first — with x64
     off, accidental f64 constants are silently downcast and invisible."""
     import jax
     import jax.numpy as jnp
@@ -319,7 +292,7 @@ def _loc(obj) -> Tuple[str, int]:
 def run_trace() -> TraceResult:
     """Run T1–T4 over the canonical hot-path instances and return every
     violation (empty = the sweep's performance contracts hold)."""
-    _configure_jax()
+    configure_jax()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -340,7 +313,7 @@ def run_trace() -> TraceResult:
     _, rp, st, prm, carry, xs = _canonical_engine()
     run_seg = _seg_runner(eng, st)
     efile, eline = _loc(eng._build_step)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         seg_jaxpr = jax.make_jaxpr(run_seg)(prm, carry, xs)
     record("T1", "engine segment runner",
            [f"host callback '{p}' inside the segment scan body"
@@ -368,7 +341,7 @@ def run_trace() -> TraceResult:
     init = {"c": np.zeros((2,), np.float32),
             "phi": np.zeros((2, 2), np.float32),
             "theta": np.zeros((2, 1), np.float32)}
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fit_jaxpr = jax.make_jaxpr(
             lambda yy, ii: fc._fit_arma_batch(yy, ii, 2, 1, steps=8))(
                 y, init)

@@ -606,6 +606,24 @@ def _run_vector(variants, traces, profile_names,
     return out
 
 
+def _resolve_jobs(jobs: Optional[int]) -> int:
+    """Worker processes for the event-loop path.  An accelerator
+    belongs to one process, and workers run the planner's JAX fit, so
+    on an accelerator backend everything runs in this process:
+    ``jobs=None`` resolves to 1 there (the CPU count otherwise) and an
+    explicit ``jobs > 1`` is refused."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return (os.cpu_count() or 1) if jobs is None else int(jobs)
+    if jobs is not None and int(jobs) > 1:
+        raise ValueError(
+            f"jobs={jobs} would start worker processes that reach for "
+            f"the {backend} device this process already holds; run with "
+            f"jobs=1 (or None) on an accelerator backend")
+    return 1
+
+
 def run_experiment(spec: ExperimentSpec, jobs: Optional[int] = None,
                    out: Optional[str] = None,
                    probes: Optional[Dict[str, Probe]] = None,
@@ -615,10 +633,12 @@ def run_experiment(spec: ExperimentSpec, jobs: Optional[int] = None,
     a spawn-based process pool (safe to call after JAX has run in the
     parent, unlike fork).
 
-    ``jobs=None`` defaults to the CPU count, capped by the variant
-    count.  In the parallel path each unique trace is spilled to a temp
-    ``.npz`` once and workers load-and-cache it at most once per
-    process — the columns are never re-pickled per variant.  Results
+    ``jobs=None`` defaults to the CPU count on a CPU backend and to 1 on
+    an accelerator, where ``jobs > 1`` is refused (see
+    ``_resolve_jobs``); either is capped by the variant count.  In the
+    parallel path each unique trace is spilled to a temp ``.npz`` once
+    and workers load-and-cache it at most once per process — the
+    columns are never re-pickled per variant.  Results
     come back in variant order regardless of completion order, so
     parallel runs are output-identical to serial ones.
     ``out`` additionally writes the JSON artifact.  ``probes`` must be
@@ -636,9 +656,7 @@ def run_experiment(spec: ExperimentSpec, jobs: Optional[int] = None,
         if key not in traces:
             traces[key] = generate_trace(v.workload)
 
-    if jobs is None:
-        jobs = os.cpu_count() or 1
-    jobs = max(1, min(int(jobs), len(variants)))
+    jobs = max(1, min(_resolve_jobs(jobs), len(variants)))
 
     if spec.engine == "vector":
         results = _run_vector(variants, traces, spec.profiles or None,

@@ -81,6 +81,9 @@ def _fit_cache_put(sig: bytes, prm: dict) -> None:
             _FIT_CACHE_STATS["evictions"] += 1
 
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 @functools.partial(jax.jit, static_argnames=("p", "q"))
 def _css_residuals(params, y, p: int, q: int):
     """Conditional-sum-of-squares residuals of ARMA(p, q)."""
@@ -92,8 +95,10 @@ def _css_residuals(params, y, p: int, q: int):
     def step(carry, t):
         e_hist = carry  # last k residuals, most recent first
         y_lags = jax.lax.dynamic_slice(ypad, (t,), (k,))[::-1]
-        ar = jnp.dot(phi, y_lags[:p]) if p else 0.0
-        ma = jnp.dot(theta, e_hist[:q]) if q else 0.0
+        # HIGHEST: the TPU's default f32 matmul rounds its inputs to
+        # bf16 (8-bit mantissa), too coarse for TPS series of ~1e4-1e6
+        ar = jnp.dot(phi, y_lags[:p], precision=_HIGHEST) if p else 0.0
+        ma = jnp.dot(theta, e_hist[:q], precision=_HIGHEST) if q else 0.0
         pred = c + ar + ma
         e = ypad[t + k] - pred
         e_hist = jnp.concatenate([e[None], e_hist[:-1]])

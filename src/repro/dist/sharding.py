@@ -41,7 +41,7 @@ class P:
 
 
 # P is a pytree node (value is the child, axes ride along as aux data) so
-# jax transforms — vmap in transformer.stack_init — pass through the box;
+# jax transforms — lax.map in transformer.stack_init — pass through the box;
 # unbox/axes_of still stop at P via is_leaf.
 jax.tree_util.register_pytree_node(
     P, lambda p: ((p.value,), p.axes), lambda axes, kids: P(kids[0], axes))

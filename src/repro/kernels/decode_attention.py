@@ -6,6 +6,12 @@ axis is split across the minor grid dimension; each step reduces a
 TPU-idiomatic grid-reduction replacing a GPU kv-split + warp-shuffle
 combine.  Ring-buffer (sliding-window) caches work unchanged because
 masking is driven entirely by the per-slot position array.
+
+Each row's current position is a scalar-prefetch operand (SMEM), and
+slot positions travel as (B, 1, T) so their block is (1, 1, block_k):
+the chip's tiling rule wants the last two block dims divisible by
+(8, 128) or equal to the array's, which a (1, block_k) slice of a
+(B, T) array breaks for B > 1.
 """
 from __future__ import annotations
 
@@ -20,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(kpos_ref, cur_ref, q_ref, k_ref, v_ref, o_ref,
+def _decode_kernel(cur_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale, window, nk, g):
     ik = pl.program_id(2)
 
@@ -33,8 +39,8 @@ def _decode_kernel(kpos_ref, cur_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (g, hd) — the GQA group
     k = k_ref[0, 0].astype(jnp.float32)          # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)          # (bk, hd)
-    kp = kpos_ref[0]                              # (bk,)
-    cur = cur_ref[0]                              # scalar
+    kp = kpos_ref[0, 0]                           # (bk,)
+    cur = cur_ref[pl.program_id(0)]               # scalar (SMEM)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # (g, bk)
     mask = (kp >= 0) & (kp <= cur)
@@ -76,23 +82,29 @@ def decode_attention(q, k, v, k_pos, cur_pos, *, scale: float,
     qg = q.reshape(B, Hkv, g, hd)
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                nk=nk, g=g)
+    # index maps receive the scalar-prefetch ref as a trailing argument
     out = pl.pallas_call(
         kernel,
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1, bk), lambda b, h, ik: (b, ik)),
-            pl.BlockSpec((1,), lambda b, h, ik: (b,)),
-            pl.BlockSpec((1, 1, g, hd), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, ik: (b, h, ik, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b, h, ik: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, bk), lambda b, h, ik, cur: (b, 0, ik)),
+                pl.BlockSpec((1, 1, g, hd),
+                             lambda b, h, ik, cur: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, ik, cur: (b, h, ik, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, ik, cur: (b, h, ik, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, g, hd),
+                                   lambda b, h, ik, cur: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((g,), jnp.float32),
+                pltpu.VMEM((g,), jnp.float32),
+                pltpu.VMEM((g, hd), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
         interpret=interpret,
-    )(k_pos, cur_pos.astype(jnp.int32), qg, k, v)
+    )(cur_pos.astype(jnp.int32), k_pos[:, None, :], qg, k, v)
     return out.reshape(B, H, hd)

@@ -8,7 +8,10 @@ block_k) tiles; head_dim and block sizes should be multiples of 128 on
 real hardware (validated here in interpret mode).
 
 GQA is expressed in the K/V BlockSpec index_map (q head h reads kv head
-h // group) — no KV replication in HBM.
+h // group) — no KV replication in HBM.  Positions travel as (B, 1, S)
+so each position block is (1, 1, block): the chip's tiling rule wants
+the last two block dims divisible by (8, 128) or equal to the array's,
+which a (1, block) slice of a (B, S) array breaks for B > 1.
 """
 from __future__ import annotations
 
@@ -36,8 +39,8 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)          # (bq, hd)
     k = k_ref[0, 0].astype(jnp.float32)          # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)          # (bk, hd)
-    qp = qpos_ref[0]                              # (bq,)
-    kp = kpos_ref[0]                              # (bk,)
+    qp = qpos_ref[0, 0]                           # (bq,)
+    kp = kpos_ref[0, 0]                           # (bk,)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     mask = (kp[None, :] >= 0) & (qp[:, None] >= 0)
@@ -81,8 +84,8 @@ def flash_attention(q, k, v, q_pos, k_pos, *, scale: float,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
+            pl.BlockSpec((1, 1, bq), lambda b, h, iq, ik: (b, 0, iq)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik: (b, 0, ik)),
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, iq, ik: (b, h, iq, 0)),
             pl.BlockSpec((1, 1, bk, hd),
                          lambda b, h, iq, ik: (b, h // g, ik, 0)),
@@ -98,4 +101,4 @@ def flash_attention(q, k, v, q_pos, k_pos, *, scale: float,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q_pos, k_pos, q, k, v)
+    )(q_pos[:, None, :], k_pos[:, None, :], q, k, v)

@@ -19,9 +19,9 @@ import sys           # noqa: E402
 import time          # noqa: E402
 from typing import Dict, Optional, Tuple  # noqa: E402
 
+from repro.jaxconfig import configure_jax  # noqa: E402
+configure_jax()
 import jax           # noqa: E402
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_dryrun_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
 import jax.numpy as jnp  # noqa: E402
 import numpy as np   # noqa: E402
 

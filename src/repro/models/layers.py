@@ -141,6 +141,9 @@ def embed_tokens(params, tokens, cfg: ModelConfig,
 
 
 def lm_head(params, x, cfg: ModelConfig):
+    """Logits in float32 (f32 accumulation of the bf16 matmul): rounded
+    to bf16, logits of a 50k vocab tie exactly often enough that greedy
+    argmax depends on which path computed them."""
     w = params["tok"].T if cfg.tie_embeddings else params["head"]
-    logits = x @ w
+    logits = jnp.matmul(x, w, preferred_element_type=jnp.float32)
     return shard(logits, "batch", "seq", "vocab")

@@ -84,13 +84,18 @@ class ServingEngine:
         self.slots = [_Slot() for _ in range(max_batch)]
         self.cache = model_mod.init_decode_cache(cfg, max_batch, max_seq)
         self.step_count = 0
+        #: device launches: one prefill per admitted request, one decode
+        #: per step with an active slot
+        self.prefill_count = 0
+        self.decode_count = 0
 
-        self._prefill = jax.jit(
-            lambda p, batch: model_mod.forward(cfg, p, batch,
-                                               return_cache=True)[:2])
+        self._prefill = jax.jit(_prefill_last, static_argnums=0)
+        # the decode cache is donated into every step and every slot
+        # write, so one copy of it is live on the device, not two
         self._decode = jax.jit(
             lambda p, toks, cache, pos: model_mod.decode_step(
-                cfg, p, toks, cache, pos))
+                cfg, p, toks, cache, pos), donate_argnums=(2,))
+        self._write = jax.jit(_write_slot, donate_argnums=(0,))
 
     # ---------------------------------------------------------------- intake
     def submit(self, req: ServeRequest) -> None:
@@ -126,11 +131,12 @@ class ServingEngine:
             pn = min(self.cfg.num_patches, 4)
             batch["patches"] = jnp.zeros((1, pn, self.cfg.d_model),
                                          jnp.dtype(self.cfg.dtype))
-        logits, pcache = self._prefill(self.params, batch)
-        next_tok = int(jnp.argmax(logits[0, -1]))
+        logits, pcache = self._prefill(self.cfg, self.params, batch)
+        self.prefill_count += 1
+        next_tok = int(jnp.argmax(logits[0]))
         offset = (batch["patches"].shape[1]
                   if self.cfg.family == "vlm" else 0)
-        self.cache = _write_slot(self.cache, pcache, slot)
+        self.cache = self._write(self.cache, pcache, jnp.int32(slot))
         st = self.slots[slot]
         st.req = req
         st.pos = S + offset
@@ -162,6 +168,7 @@ class ServingEngine:
                 pos[i] = s.pos
         logits, self.cache = self._decode(self.params, jnp.asarray(toks),
                                           self.cache, jnp.asarray(pos))
+        self.decode_count += 1
         nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
         for i, s in enumerate(self.slots):
             if s.req is None:
@@ -177,7 +184,14 @@ class ServingEngine:
             self.step()
 
 
-def _write_slot(cache, prefill_cache, slot: int):
+def _prefill_last(cfg: ModelConfig, params, batch):
+    """Prefill one request: (last-position logits (1, V), its cache)."""
+    logits, cache, _ = model_mod.forward(cfg, params, batch,
+                                         return_cache=True)
+    return logits[:, -1], cache
+
+
+def _write_slot(cache, prefill_cache, slot):
     """Write a single-request prefill cache into decode-cache slot `slot`.
 
     Decode leaves are stacked (L, B, W, ...); prefill leaves are

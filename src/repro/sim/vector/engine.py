@@ -50,6 +50,11 @@ from repro.sim.vector.report import ReplicaAccumulator
 
 _EPS = 1e-9
 _DRAIN_RING = 3   # scale-ins serve ~1 bucket before reaping to spot
+# Every matmul of the step runs at HIGHEST precision: the TPU's default
+# f32 matmul rounds its inputs to bf16 (8-bit mantissa), which would
+# round token masses of ~1e4-1e6 per bucket by up to 0.4% each step.
+# The operands are small ([M, C] one-hots, [C, J, J] routing fractions).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 #: carry keys the hourly control boundary *reads* (aggregate signals
 #: fed to the planner) and the four it *writes* — the batched boundary
@@ -117,6 +122,10 @@ class _Static:
                 self.local_b.tobytes(), self.remote_b.tobytes())
 
 
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=_HIGHEST)
+
+
 def _build_step(st: _Static):
     C, J, L, LD, dt = st.C, st.J, st.L, st.LD, st.dt
     f32 = jnp.float32
@@ -146,10 +155,10 @@ def _build_step(st: _Static):
         reap = carry["drainq"][idx_d]
         drainq = carry["drainq"].at[idx_d].set(0.0)
         spot = carry["spot"] + reap.sum(axis=0)
-        warm = carry["warm"] + PM @ reap
+        warm = carry["warm"] + _mm(PM, reap)
         draining = drainq.sum(axis=0)
         pend = ring.sum(axis=0)
-        dep_c = PMT @ carry["dep"]
+        dep_c = _mm(PMT, carry["dep"])
         down = carry["down"]
 
         # -- 2. utilization (reserved incl. queued, like Endpoint.util)
@@ -182,7 +191,8 @@ def _build_step(st: _Static):
         hq = prm["has_qm"]
         a_npo = jnp.stack([x["iw_n"], x["iw_p"], x["iw_o"]]) + \
             (1.0 - hq) * jnp.stack([x["niw_n"], x["niw_p"], x["niw_o"]])
-        r_n, r_p, r_o = jnp.einsum("scj,cjk->sck", a_npo, Rm)
+        r_n, r_p, r_o = jnp.einsum("scj,cjk->sck", a_npo, Rm,
+                                   precision=_HIGHEST)
 
         # -- 5. scaling policy ----------------------------------------
         cd_now = jnp.maximum(carry["cd"] - 1.0, 0.0)
@@ -219,8 +229,8 @@ def _build_step(st: _Static):
         # chiron: offline-profile backpressure + NIW backlog drain.
         # The event loop's backlog signal sees NIW parked since the
         # previous tick, so the current bucket's inflow counts too.
-        park_tok = (PM @ (carry["park_p"] + carry["park_o"]
-                          + hq * (x["niw_p"] + x["niw_o"]))).sum(axis=1)
+        park_tok = _mm(PM, carry["park_p"] + carry["park_o"]
+                       + hq * (x["niw_p"] + x["niw_o"])).sum(axis=1)
         bk_c = park_tok[jnp.asarray(st.cell_model)] / float(J)
         prof = prm["chiron_prof"][:, None]
         req_i = jnp.ceil(obs / jnp.maximum(prm["chiron_theta"] * prof,
@@ -247,13 +257,13 @@ def _build_step(st: _Static):
                         jnp.minimum(1.0, avail_j / jnp.maximum(req_j,
                                                                _EPS)), 0.0)
         grant = want_up * fac[None, :]
-        g_m = PM @ grant
-        ratio = grant / jnp.maximum(PMT @ g_m, _EPS)
-        warm_take = jnp.minimum(grant, (PMT @ warm) * ratio)
+        g_m = _mm(PM, grant)
+        ratio = grant / jnp.maximum(_mm(PMT, g_m), _EPS)
+        warm_take = jnp.minimum(grant, _mm(PMT, warm) * ratio)
         cold = grant - warm_take
-        warm = jnp.maximum(warm - PM @ warm_take, 0.0)
+        warm = jnp.maximum(warm - _mm(PM, warm_take), 0.0)
         spot = spot - grant.sum(axis=0)
-        wloc_c = PMT @ carry["wloc"]
+        wloc_c = _mm(PMT, carry["wloc"])
         cold_loc = cold * jnp.where(wloc_c > 0.5, 1.0, 0.0)
         cold_rem = cold - cold_loc
         rows3 = jnp.concatenate([jnp.mod(b + SWAP, L),
@@ -262,7 +272,7 @@ def _build_step(st: _Static):
         ring = ring.at[rows3, CI3].add(
             jnp.concatenate([warm_take, cold_loc, cold_rem]))
         wloc = jnp.maximum(carry["wloc"],
-                           jnp.where(PM @ cold > _EPS, 1.0, 0.0))
+                           jnp.where(_mm(PM, cold) > _EPS, 1.0, 0.0))
         want_dn = jnp.minimum(jnp.maximum(-delta, 0.0), live)
         live_after = live - want_dn
         drainq = drainq.at[jnp.mod(b + LD - 1, LD)].add(want_dn)
@@ -278,7 +288,8 @@ def _build_step(st: _Static):
         park_n, park_p, park_o = (park_n - rel_n, park_p - rel_p,
                                   park_o - rel_o)
         q_add_n, q_add_p, q_add_o = jnp.einsum(
-            "scj,cjk->sck", jnp.stack([rel_n, rel_p, rel_o]), Rm)
+            "scj,cjk->sck", jnp.stack([rel_n, rel_p, rel_o]), Rm,
+            precision=_HIGHEST)
         relcum = carry["relcum"] + need
         per_inst = jnp.where(u < prm["qm_two"], 2.0,
                              jnp.where(u < prm["qm_one"], 1.0, 0.0))
@@ -341,7 +352,8 @@ def _build_step(st: _Static):
             jnp.clip(qp * dt / jnp.maximum(adm_p + 0.5 * rel_tok, _EPS),
                      0.0, 1e6), 0.0)
         delay_h, tbt_h = jnp.einsum("cjk,sck->scj", Rm,
-                                    jnp.stack([delay_dest, tbt]))
+                                    jnp.stack([delay_dest, tbt]),
+                                    precision=_HIGHEST)
         pk_fin = park_n.sum(axis=1)
         nw = jnp.where(hq > 0.5,
                        jnp.clip(0.5 * dt + pk_fin * dt /
@@ -394,15 +406,10 @@ def _compiled_segments(st: _Static):
         return jax.lax.scan(lambda c, x: step(prm, c, x), carry, xs)
 
     # donated carry: the scan consumes the previous segment's state
-    # in place (R6 checks this under src/repro/sim/vector).  The
-    # batched runner must NOT donate: its carry stays device-resident
-    # between segments (``carry = out``), and donating device-resident
-    # buffers into this executable corrupts the CPU-backend heap
-    # (double free) on jaxlib 0.4.x — the single path only ever feeds
-    # freshly transferred host arrays, where donation is safe.
+    # in place (R6 checks this under src/repro/sim/vector)
     seg_single = jax.jit(run_seg, donate_argnums=(1,))  # reprolint: disable=R6 -- cache-once: stored in module-level _SEG_CACHE keyed by static config
-    seg_batched = jax.jit(  # reprolint: disable=R6 -- device-resident carry chain: donation would double-free on the CPU backend; cache-once in _SEG_CACHE
-        jax.vmap(run_seg, in_axes=(0, 0, None)))
+    seg_batched = jax.jit(  # reprolint: disable=R6 -- cache-once: stored in module-level _SEG_CACHE keyed by static config
+        jax.vmap(run_seg, in_axes=(0, 0, None)), donate_argnums=(1,))
     _SEG_CACHE[key] = (seg_single, seg_batched)
     return _SEG_CACHE[key]
 
@@ -632,6 +639,13 @@ class VectorBatch:
         if isinstance(plan, tuple):
             targets, forecasts = plan
             plan = Plan(t=t, targets=targets, forecasts=forecasts)
+        # a NaN/inf written into the carry would poison every later
+        # bucket of the scan without an error: refuse it here
+        bad = sorted(k for d in (plan.targets, plan.forecasts)
+                     for k, v in d.items() if not math.isfinite(v))
+        if bad:
+            raise ValueError(f"plan at t={t} has non-finite targets or "
+                             f"forecasts for {bad}")
         if plan.placement is not None:
             for a in plan.placement.actions:
                 bkt = int(round(a.effective_at / st.dt))

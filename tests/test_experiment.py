@@ -183,6 +183,25 @@ def test_parallel_matches_serial_and_completion_from_report():
         assert 0.0 < a.completion <= 1.0
 
 
+@pytest.mark.parametrize("backend,jobs,want", [
+    ("cpu", None, "cpus"), ("cpu", 3, 3), ("tpu", None, 1), ("tpu", 1, 1),
+    ("tpu", 2, ValueError),
+])
+def test_jobs_stay_in_process_on_an_accelerator(monkeypatch, backend, jobs,
+                                                want):
+    """An accelerator belongs to one process: no worker pool there."""
+    import os
+
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="jobs=2"):
+            exp_mod._resolve_jobs(jobs)
+        return
+    want = (os.cpu_count() or 1) if want == "cpus" else want
+    assert exp_mod._resolve_jobs(jobs) == want
+
+
 def test_consecutive_runs_share_trace_without_reset():
     """The footgun regression: two back-to-back runs over the *same*
     request list produce field-identical Reports — the run path owns

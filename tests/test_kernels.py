@@ -67,6 +67,7 @@ def test_decode_attention_sweep(dtype, B, H, Hkv, T, hd, bk, window):
 
 @pytest.mark.parametrize("b,c,h,p,n", [
     (1, 4, 2, 8, 16), (2, 8, 3, 16, 32), (1, 16, 1, 32, 8),
+    (2, 48, 2, 8, 16),                  # three chunk tiles of 16
 ])
 def test_ssd_scan_sweep(b, c, h, p, n):
     ks = jax.random.split(jax.random.PRNGKey(2), 3)

@@ -51,3 +51,18 @@ def test_engine_multi_request_batched():
         assert len(r.tokens) == 5
         want = greedy_rollout(cfg, params, r.prompt, 5)
         assert r.tokens == want, (r.rid, r.tokens, want)
+
+
+def test_serve_launcher_completes_mixed_lengths(capsys):
+    """`python -m repro.launch.serve` on the reduced model: every request
+    completes, one prefill per request, decode launches counted."""
+    from repro.launch import serve
+    args = serve.parse_args(["--requests", "5", "--max-new", "4"])
+    cfg, _, eng, reqs = serve.build(args)
+    serve.serve(eng, reqs)
+    assert [r.prompt_tokens for r in reqs] == [256, 1024, 256, 1024, 256]
+    assert all(len(r.tokens) == 4 for r in reqs)
+    assert eng.prefill_count == 5
+    assert 3 <= eng.decode_count <= eng.step_count
+    assert serve.main(["--requests", "2", "--max-new", "2"]) == 0
+    assert "served 2 requests" in capsys.readouterr().out

@@ -185,6 +185,26 @@ def test_hourly_plan_crosses_into_array_state(small_trace):
     assert cv["has_om"][0, regions.index(r1)] == 0.0
 
 
+def test_non_finite_plan_is_refused_at_the_boundary(small_trace):
+    """A NaN forecast must not reach the carry: it would poison every
+    later bucket of the scan without an error."""
+    from repro.sim.vector.engine import _init_carry
+    models, regions = list(small_trace.models), list(small_trace.regions)
+    targets = {(m, r): 4 for m in models for r in regions}
+    cfg = SimConfig(policy=make_policy("lt-i"),
+                    controller=_StubController(targets),
+                    initial_instances=2, spot_spare=20)
+    vb = VectorBatch(small_trace, [cfg], ["nan"], models=models,
+                     regions=regions, batched=False)
+    cv = {k: np.array(v) for k, v in _init_carry(vb.st, vb.rps[0]).items()}
+    forecasts = {k: 100.0 for k in targets}
+    forecasts[(models[0], regions[0])] = float("nan")
+    with pytest.raises(ValueError, match="non-finite"):
+        vb._apply_plan(0, cv, 3600.0, Plan(t=3600.0, targets=targets,
+                                           forecasts=forecasts), [])
+    assert (cv["fc"] == 0.0).all()
+
+
 def test_lt_targets_actuate_like_event_loop(small_trace):
     """End-to-end: the same stub plan drives both engines; the fleets
     they scale to agree (LT-I jumps straight to the hourly target)."""
