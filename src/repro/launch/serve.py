@@ -2,7 +2,8 @@
 
 Drives the continuous-batching :class:`ServingEngine` with a mixed
 IW-F/IW-N request stream and a SageServe scheduler (default DPA),
-printing TTFT/E2E step counts — the single-instance slice of the full
+printing TTFT/E2E step counts, and each request's queue wait and TTFT in
+milliseconds from its host timestamps — the single-instance slice of the full
 SageServe stack (the cluster-level behaviour lives in the simulator;
 see examples/serve_cluster.py).
 
@@ -102,7 +103,9 @@ def main(argv=None):
     for r in reqs:
         print(f"req {r.rid} [{r.tier}] prompt={r.prompt_tokens} "
               f"ttft_step={r.ttft_step} done_step={r.done_step} "
-              f"tokens={len(r.tokens)}")
+              f"tokens={len(r.tokens)} "
+              f"queue_wait_ms={(r.admit_ns - r.submit_ns) / 1e6:.3f} "
+              f"ttft_ms={(r.token_ns[0] - r.submit_ns) / 1e6:.3f}")
     print(f"served {len(reqs)} requests on {cfg.name} "
           f"({cfg.num_layers} layers) in {eng.step_count} engine steps "
           f"with {args.scheduler.upper()} scheduling")

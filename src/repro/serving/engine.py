@@ -9,12 +9,33 @@ the simulator's ``Request``, so schedulers and the NIW queue manager
 run unchanged against either path.  At smoke scale this runs actual
 forward passes; on TPU the same engine drives the sharded model (see
 launch/serve.py).
+
+Each ``step()`` is a tree of host spans (``repro.serving.telemetry``),
+which a profiler session puts on the device trace's clock::
+
+    engine.step              args: step, engine
+      engine.admit           only in a step that admits
+        engine.schedule      the scheduler's ordering
+        engine.prefill       one per admitted request; args: rid, tokens
+          engine.prefill.launch   prompt upload and prefill launch
+          engine.prefill.pull     first-token argmax and pull (waits for it)
+          engine.slot_write       slot-write launch
+      engine.decode          args: rows
+        engine.decode.inputs      token and position build and uploads
+        engine.decode.launch      decode launch
+        engine.decode.pull        argmax and pull (waits for the decode)
+        engine.decode.emit        tokens appended, slots finished
+
+Every request carries host timestamps (``time.perf_counter_ns``), always
+on: ``submit_ns``, ``admit_ns`` (its prefill starts) and one ``token_ns``
+per token, taken when the token reached the host.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
 import math
+import time
 from typing import Callable, Dict, List, Optional, Tuple, Union
 
 import jax
@@ -24,6 +45,7 @@ import numpy as np
 from repro.api.registry import resolve
 from repro.configs.base import ModelConfig
 from repro.models import model as model_mod
+from repro.serving.telemetry import span, step_span
 
 
 @dataclasses.dataclass
@@ -46,6 +68,9 @@ class ServeRequest:
     tokens: List[int] = dataclasses.field(default_factory=list)
     ttft_step: Optional[int] = None
     done_step: Optional[int] = None
+    submit_ns: Optional[int] = None
+    admit_ns: Optional[int] = None
+    token_ns: List[int] = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         if not self.prompt_tokens:
@@ -70,6 +95,8 @@ class _Slot:
 
 
 class ServingEngine:
+    _ids = itertools.count()
+
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 4,
                  max_seq: int = 512,
                  scheduler: Union[str, Callable] = "fcfs",
@@ -84,6 +111,8 @@ class ServingEngine:
         self.slots = [_Slot() for _ in range(max_batch)]
         self.cache = model_mod.init_decode_cache(cfg, max_batch, max_seq)
         self.step_count = 0
+        #: this engine's index in the process (the ``engine`` of its spans)
+        self.index = next(ServingEngine._ids)
         #: device launches: one prefill per admitted request, one decode
         #: per step with an active slot
         self.prefill_count = 0
@@ -99,6 +128,7 @@ class ServingEngine:
 
     # ---------------------------------------------------------------- intake
     def submit(self, req: ServeRequest) -> None:
+        req.submit_ns = time.perf_counter_ns()
         self.queue.append(req)
 
     @property
@@ -114,15 +144,17 @@ class ServingEngine:
         free = [i for i, s in enumerate(self.slots) if s.req is None]
         if not free or not self.queue:
             return
-        self.queue = self.order_fn(self.queue, float(self.step_count))
-        while free and self.queue:
-            req = self.queue.pop(0)
-            slot = free.pop(0)
-            self._prefill_into(slot, req)
+        with span("engine.admit"):
+            with span("engine.schedule"):
+                self.queue = self.order_fn(self.queue,
+                                           float(self.step_count))
+            while free and self.queue:
+                req = self.queue.pop(0)
+                slot = free.pop(0)
+                self._prefill_into(slot, req)
 
-    def _prefill_into(self, slot: int, req: ServeRequest) -> None:
-        S = len(req.prompt)
-        batch = {"tokens": jnp.asarray(req.prompt, jnp.int32)[None, :]}
+    def _prompt_batch(self, prompt: np.ndarray) -> Dict:
+        batch = {"tokens": jnp.asarray(prompt, jnp.int32)[None, :]}
         if self.cfg.family == "audio":
             batch["frames"] = jnp.zeros(
                 (1, self.cfg.encoder_seq, self.cfg.d_model),
@@ -131,20 +163,31 @@ class ServingEngine:
             pn = min(self.cfg.num_patches, 4)
             batch["patches"] = jnp.zeros((1, pn, self.cfg.d_model),
                                          jnp.dtype(self.cfg.dtype))
-        logits, pcache = self._prefill(self.cfg, self.params, batch)
-        self.prefill_count += 1
-        next_tok = int(jnp.argmax(logits[0]))
-        offset = (batch["patches"].shape[1]
-                  if self.cfg.family == "vlm" else 0)
-        self.cache = self._write(self.cache, pcache, jnp.int32(slot))
-        st = self.slots[slot]
-        st.req = req
-        st.pos = S + offset
-        st.remaining = req.max_new_tokens - 1
-        req.tokens.append(next_tok)
-        req.ttft_step = self.step_count
-        if st.remaining <= 0:
-            self._finish(slot)
+        return batch
+
+    def _prefill_into(self, slot: int, req: ServeRequest) -> None:
+        S = len(req.prompt)
+        with span("engine.prefill", rid=req.rid, tokens=S):
+            with span("engine.prefill.launch"):
+                req.admit_ns = time.perf_counter_ns()
+                batch = self._prompt_batch(req.prompt)
+                logits, pcache = self._prefill(self.cfg, self.params, batch)
+                self.prefill_count += 1
+            with span("engine.prefill.pull"):
+                next_tok = int(jnp.argmax(logits[0]))
+                req.token_ns.append(time.perf_counter_ns())
+            offset = (batch["patches"].shape[1]
+                      if self.cfg.family == "vlm" else 0)
+            with span("engine.slot_write"):
+                self.cache = self._write(self.cache, pcache, jnp.int32(slot))
+            st = self.slots[slot]
+            st.req = req
+            st.pos = S + offset
+            st.remaining = req.max_new_tokens - 1
+            req.tokens.append(next_tok)
+            req.ttft_step = self.step_count
+            if st.remaining <= 0:
+                self._finish(slot)
 
     def _finish(self, slot: int) -> None:
         st = self.slots[slot]
@@ -157,27 +200,40 @@ class ServingEngine:
         """One engine iteration: admit waiting requests, decode one token
         for every active slot."""
         self.step_count += 1
-        self._admit()
-        if self.active == 0:
-            return
-        toks = np.zeros((self.max_batch, 1), np.int32)
-        pos = np.zeros((self.max_batch,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s.req is not None:
-                toks[i, 0] = s.req.tokens[-1]
-                pos[i] = s.pos
-        logits, self.cache = self._decode(self.params, jnp.asarray(toks),
-                                          self.cache, jnp.asarray(pos))
-        self.decode_count += 1
-        nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
-        for i, s in enumerate(self.slots):
-            if s.req is None:
-                continue
-            s.req.tokens.append(int(nxt[i]))
-            s.pos += 1
-            s.remaining -= 1
-            if s.remaining <= 0 or s.pos >= self.max_seq - 1:
-                self._finish(i)
+        with step_span("engine.step", self.step_count, engine=self.index):
+            self._admit()
+            rows = self.active
+            if rows:
+                with span("engine.decode", rows=rows):
+                    self._decode_one()
+
+    def _decode_one(self) -> None:
+        """Decode one token for every active slot."""
+        with span("engine.decode.inputs"):
+            toks = np.zeros((self.max_batch, 1), np.int32)
+            pos = np.zeros((self.max_batch,), np.int32)
+            for i, s in enumerate(self.slots):
+                if s.req is not None:
+                    toks[i, 0] = s.req.tokens[-1]
+                    pos[i] = s.pos
+            toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+        with span("engine.decode.launch"):
+            logits, self.cache = self._decode(self.params, toks, self.cache,
+                                              pos)
+            self.decode_count += 1
+        with span("engine.decode.pull"):
+            nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+            t = time.perf_counter_ns()
+        with span("engine.decode.emit"):
+            for i, s in enumerate(self.slots):
+                if s.req is None:
+                    continue
+                s.req.tokens.append(int(nxt[i]))
+                s.req.token_ns.append(t)
+                s.pos += 1
+                s.remaining -= 1
+                if s.remaining <= 0 or s.pos >= self.max_seq - 1:
+                    self._finish(i)
 
     def run(self, max_steps: int = 10_000) -> None:
         while self.has_work and self.step_count < max_steps:
