@@ -16,7 +16,10 @@ MLA caches the compressed latent instead::
 
 Prefill uses a q-block lazy-flash (lax.scan over query blocks) so the
 (S, T) score matrix is never fully materialized; decode uses the absorbed
-MLA form / direct GQA reduction.
+MLA form / direct GQA reduction.  A decode step only reads each layer's
+cache: every layer returns its new token's rows, and the caller writes
+all layers' rows into the stacked cache at once (``write_decode_rows``),
+so the cache is neither copied nor re-emitted by the layer scan.
 """
 from __future__ import annotations
 
@@ -263,23 +266,101 @@ def _mla_forward(params, x, cfg: ModelConfig, positions, *,
 # Single-token decode
 # --------------------------------------------------------------------------
 
+def _decode_mask(kpos, cur_pos, window: int = 0):
+    """Which cached positions a decoding token attends, besides itself.
+
+    kpos: (B, W) positions held in the cache before this step; cur_pos:
+    (B,).  The new token's own row is not in the cache yet, and its slot
+    (cur_pos % W) may hold a stale row with pos == cur_pos from an earlier,
+    longer request, hence ``<``.  In a ring (W == window) that slot holds
+    cur_pos - W, which the window drops."""
+    mask = (kpos >= 0) & (kpos < cur_pos[:, None])
+    if window:
+        mask &= (cur_pos[:, None] - kpos) < window
+    return mask
+
+
+def _decode_weights(scores, score_new, mask):
+    """Softmax over the cached positions' scores joined with the new
+    token's own.  scores: (..., W) f32; score_new: (...); mask broadcast
+    to scores.  Returns (w_cached (..., W), w_new (...))."""
+    scores = jnp.where(mask, scores, NEG_INF)
+    w = jax.nn.softmax(
+        jnp.concatenate([scores, score_new[..., None]], axis=-1), axis=-1)
+    return w[..., :-1], w[..., -1]
+
+
+def _attend_decode(q, k, v, k_new, v_new, mask, scale):
+    """One query per row over the cache plus the row's own new key/value.
+
+    q: (B,1,H,hd); k/v: (B,W,Hkv,hd) as stored; k_new/v_new: (B,Hkv,hd);
+    mask: (B,W).  QK and AV read the cache in its stored dtype with float32
+    accumulation (MXU dots); scores and softmax are float32."""
+    B, _, H, hd = q.shape
+    Hkv = k.shape[2]
+    qg = q.reshape(B, Hkv, H // Hkv, hd)
+    scores = jnp.einsum("bkgd,btkd->bkgt", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    score_new = jnp.einsum("bkgd,bkd->bkg", qg, k_new,
+                           preferred_element_type=jnp.float32) * scale
+    w, w_new = _decode_weights(scores, score_new, mask[:, None, None, :])
+    out = jnp.einsum("bkgt,btkd->bkgd", w.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    out += w_new[..., None] * v_new[:, :, None, :].astype(jnp.float32)
+    return out.reshape(B, 1, H, v.shape[-1]).astype(q.dtype)
+
+
+def write_decode_rows(cache: Dict, rows: Dict, cur_pos) -> Dict:
+    """Write each layer's new-token rows into a stacked decode cache.
+
+    cache leaves are (L, B, W, ...); rows hold the same keys but "pos",
+    each (L, B, ...) in the cache's dtype.  Row b goes to slot
+    cur_pos[b] % W of every layer, and pos there becomes cur_pos[b];
+    nothing else changes.  With the cache donated the update is in place
+    (one scatter over layer, row and slot, so the window it writes is the
+    cache's minor dimensions)."""
+    L, B, W = cache["pos"].shape
+    slot = jnp.mod(cur_pos, W)
+    pos = jnp.broadcast_to(cur_pos.astype(jnp.int32), (L, B))
+    new = dict(rows, pos=pos)
+    if flags.WHERE_CACHE_UPDATE:
+        sel = (jnp.arange(W, dtype=jnp.int32)[None, :]
+               == slot[:, None])                         # (B, W)
+
+        def put(c, r):
+            s = sel.reshape((1, B, W) + (1,) * (c.ndim - 3))
+            return jnp.where(s, r[:, :, None], c)
+    else:
+        lidx = jnp.arange(L)[:, None]
+        bidx = jnp.arange(B)[None, :]
+
+        def put(c, r):
+            return c.at[lidx, bidx, slot[None, :]].set(r)
+    return {name: put(c, new[name]) for name, c in cache.items()}
+
+
 def attention_decode(params, x, cfg: ModelConfig, cache: Dict,
                      cur_pos: jnp.ndarray,
                      window: Optional[int] = None):
     """x: (B, 1, D); cur_pos: (B,) absolute position of the new token.
 
-    Returns (y, new_cache).
+    ``cache`` (one layer) is only read.  Returns (y, rows): the new
+    token's cache rows, {"k", "v"} each (B, Hkv, hd), for
+    ``write_decode_rows`` to put at slot cur_pos % W once all layers ran.
     """
     if cfg.use_mla:
         return _mla_decode(params, x, cfg, cache, cur_pos)
     B = x.shape[0]
     hd = cfg.head_dim
-    W = cache["k"].shape[1]
     q = (x @ params["wq"])
     k = (x @ params["wk"])
     v = (x @ params["wv"])
     if cfg.use_qkv_bias:
         q, k, v = q + params["bq"], k + params["bk"], v + params["bv"]
+    # the projections keep their row layout: without the barrier XLA lays
+    # q out head-major for the attention and so copies wq, wk and wv into
+    # that layout in every layer of every decode (on a TPU v5e)
+    q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(B, 1, cfg.num_heads, hd)
     k = k.reshape(B, 1, cfg.num_kv_heads, hd)
     v = v.reshape(B, 1, cfg.num_kv_heads, hd)
@@ -287,34 +368,13 @@ def attention_decode(params, x, cfg: ModelConfig, cache: Dict,
         q = apply_rope(q, cur_pos[:, None], cfg.rope_theta)
         k = apply_rope(k, cur_pos[:, None], cfg.rope_theta)
 
-    slot = jnp.mod(cur_pos, W)  # ring index (== pos when W == max_seq)
-    if flags.WHERE_CACHE_UPDATE:
-        sel = (jnp.arange(W, dtype=jnp.int32)[None, :]
-               == slot[:, None])                         # (B, W)
-        new_cache = {
-            "k": jnp.where(sel[:, :, None, None],
-                           k[:, 0][:, None], cache["k"]),
-            "v": jnp.where(sel[:, :, None, None],
-                           v[:, 0][:, None], cache["v"]),
-            "pos": jnp.where(sel, cur_pos[:, None].astype(jnp.int32),
-                             cache["pos"]),
-        }
-    else:
-        bidx = jnp.arange(B)
-        new_cache = {
-            "k": cache["k"].at[bidx, slot].set(k[:, 0]),
-            "v": cache["v"].at[bidx, slot].set(v[:, 0]),
-            "pos": cache["pos"].at[bidx, slot].set(cur_pos.astype(jnp.int32)),
-        }
-    kpos = new_cache["pos"]
-    mask = (kpos <= cur_pos[:, None]) & (kpos >= 0)
-    win = window or cfg.sliding_window
-    if win:
-        mask &= (cur_pos[:, None] - kpos) < win
-    out = _attend(q, new_cache["k"], new_cache["v"], mask[:, None, :],
-                  1.0 / math.sqrt(hd))
+    rows = {"k": k[:, 0].astype(cache["k"].dtype),
+            "v": v[:, 0].astype(cache["v"].dtype)}
+    mask = _decode_mask(cache["pos"], cur_pos, window or cfg.sliding_window)
+    out = _attend_decode(q, cache["k"], cache["v"], rows["k"], rows["v"],
+                         mask, 1.0 / math.sqrt(hd))
     y = out.reshape(B, 1, cfg.num_heads * hd) @ params["wo"]
-    return shard(y, "batch", None, "embed_act"), new_cache
+    return shard(y, "batch", None, "embed_act"), rows
 
 
 def cross_attention_decode(params, x, cfg: ModelConfig, cross_cache: Dict):
@@ -333,13 +393,15 @@ def cross_attention_decode(params, x, cfg: ModelConfig, cross_cache: Dict):
 
 
 def _mla_decode(params, x, cfg: ModelConfig, cache: Dict, cur_pos):
-    """Absorbed-matrix MLA decode: attention runs in the latent space."""
+    """Absorbed-matrix MLA decode: attention runs in the latent space.
+
+    Same contract as ``attention_decode``: the cache is only read, and the
+    new token's rows {"ckv": (B, lora), "krope": (B, rope_dim)} return."""
     m = cfg.mla
     B = x.shape[0]
     H = cfg.num_heads
     nope, rope_d, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     lora = m.kv_lora_rank
-    W = cache["ckv"].shape[1]
 
     q_lat = apply_norm(params["q_norm"], x @ params["wq_a"], cfg)
     q = (q_lat @ params["wq_b"]).reshape(B, 1, H, nope + rope_d)
@@ -350,29 +412,29 @@ def _mla_decode(params, x, cfg: ModelConfig, cache: Dict, cur_pos):
     ckv_new = apply_norm(params["kv_norm"], kv[..., :lora], cfg)
     krope_new = apply_rope(kv[..., None, lora:], cur_pos[:, None],
                            cfg.rope_theta)[:, :, 0, :]
+    rows = {"ckv": ckv_new[:, 0].astype(cache["ckv"].dtype),
+            "krope": krope_new[:, 0].astype(cache["krope"].dtype)}
+    ckv_n = rows["ckv"][:, None].astype(jnp.float32)        # (B,1,lora)
+    krope_n = rows["krope"][:, None].astype(jnp.float32)
 
-    slot = jnp.mod(cur_pos, W)
-    bidx = jnp.arange(B)
-    new_cache = {
-        "ckv": cache["ckv"].at[bidx, slot].set(ckv_new[:, 0]),
-        "krope": cache["krope"].at[bidx, slot].set(krope_new[:, 0]),
-        "pos": cache["pos"].at[bidx, slot].set(cur_pos.astype(jnp.int32)),
-    }
     # absorb W_uk into q: (B,1,H,nope) x (lora, H, nope) -> (B,1,H,lora)
     wk_b = params["wk_b"].reshape(lora, H, nope)
     q_abs = jnp.einsum("bshn,lhn->bshl", q_nope.astype(jnp.float32),
                        wk_b.astype(jnp.float32))
-    scores = jnp.einsum("bshl,btl->bhst", q_abs,
-                        new_cache["ckv"].astype(jnp.float32))
-    scores += jnp.einsum("bshr,btr->bhst", q_rope.astype(jnp.float32),
-                         new_cache["krope"].astype(jnp.float32))
-    scores *= 1.0 / math.sqrt(nope + rope_d)
-    mask = (new_cache["pos"] <= cur_pos[:, None]) & (new_cache["pos"] >= 0)
-    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhst,btl->bshl", w,
-                     new_cache["ckv"].astype(jnp.float32))
+    q_rope = q_rope.astype(jnp.float32)
+    ckv = cache["ckv"].astype(jnp.float32)
+    scores = jnp.einsum("bshl,btl->bhst", q_abs, ckv)
+    scores += jnp.einsum("bshr,btr->bhst", q_rope,
+                         cache["krope"].astype(jnp.float32))
+    score_new = (jnp.einsum("bshl,bsl->bhs", q_abs, ckv_n)
+                 + jnp.einsum("bshr,bsr->bhs", q_rope, krope_n))
+    scale = 1.0 / math.sqrt(nope + rope_d)
+    mask = _decode_mask(cache["pos"], cur_pos)
+    w, w_new = _decode_weights(scores * scale, score_new * scale,
+                               mask[:, None, None, :])
+    ctx = (jnp.einsum("bhst,btl->bshl", w, ckv)
+           + jnp.einsum("bhs,bsl->bshl", w_new, ckv_n))
     wv_b = params["wv_b"].reshape(lora, H, vd)
     out = jnp.einsum("bshl,lhv->bshv", ctx, wv_b.astype(jnp.float32))
     y = out.reshape(B, 1, H * vd).astype(x.dtype) @ params["wo"]
-    return shard(y, "batch", None, "embed_act"), new_cache
+    return shard(y, "batch", None, "embed_act"), rows

@@ -125,16 +125,16 @@ def decoder_decode(params, tokens, cfg: ModelConfig, cache, cross_cache,
     def blk(lp, h, cs):
         c_self, c_cross = cs
         a = apply_norm(lp["norm1"], h, cfg)
-        a, nc = attn.attention_decode(lp["self_attn"], a, cfg, c_self,
-                                      cur_pos)
+        a, rows = attn.attention_decode(lp["self_attn"], a, cfg, c_self,
+                                        cur_pos)
         h = h + a
         xh = apply_norm(lp["norm_x"], h, cfg)
         xa = attn.cross_attention_decode(lp["cross_attn"], xh, cfg, c_cross)
         h = h + xa
         m = apply_norm(lp["norm2"], h, cfg)
-        return h + apply_mlp(lp["mlp"], m, cfg), (nc, c_cross), 0.0
+        return h + apply_mlp(lp["mlp"], m, cfg), rows, 0.0
 
-    x, (new_cache, _), _ = _scan_stack(params["dec_layers"], x, blk,
-                                       caches=(cache, cross_cache))
+    x, rows, _ = _scan_stack(params["dec_layers"], x, blk,
+                             caches=(cache, cross_cache))
     x = apply_norm(params["final_norm"], x, cfg)
-    return x, new_cache
+    return x, attn.write_decode_rows(cache, rows, cur_pos)

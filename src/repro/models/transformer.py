@@ -77,16 +77,18 @@ def apply_block(params, x, cfg: ModelConfig, positions, *,
 
 def apply_block_decode(params, x, cfg: ModelConfig, cache, cur_pos, *,
                        window: Optional[int] = None):
+    """One block of a decode step.  Returns (x, rows): the new token's
+    cache rows (``attn.attention_decode``); ``cache`` is only read."""
     h = apply_norm(params["norm1"], x, cfg)
-    a, new_cache = attn.attention_decode(params["attn"], h, cfg, cache,
-                                         cur_pos, window=window)
+    a, rows = attn.attention_decode(params["attn"], h, cfg, cache,
+                                    cur_pos, window=window)
     x = x + a
     h = apply_norm(params["norm2"], x, cfg)
     if "moe" in params:
         f, _ = moe_mod.apply_moe(params["moe"], h, cfg, decode=True)
     else:
         f = apply_mlp(params["mlp"], h, cfg)
-    return x + f, new_cache
+    return x + f, rows
 
 
 # --------------------------------------------------------------------------
@@ -127,7 +129,9 @@ def init_lm(cfg: ModelConfig, key) -> Dict:
 
 
 def _scan_stack(layer_params, x, fn, caches=None, remat: bool = False):
-    """Scan fn over a stacked layer tree; optionally thread per-layer cache."""
+    """Scan fn over a stacked layer tree; ``caches`` (stacked like the
+    layers) are handed to fn layer by layer.  Returns (x, what fn emitted
+    per layer, stacked; aux)."""
     if remat:
         fn = jax.checkpoint(fn, policy=jax.checkpoint_policies.nothing_saveable)
 
@@ -173,19 +177,19 @@ def backbone_forward(params, x, cfg: ModelConfig, positions, *,
 
 def backbone_decode(params, x, cfg: ModelConfig, cache, cur_pos, *,
                     window: Optional[int] = None):
+    """The layer scan reads the stacked cache and emits only each layer's
+    new rows; one write after it puts them into the cache."""
     def blk(lp, h, c):
-        y, nc = apply_block_decode(lp, h, cfg, c, cur_pos, window=window)
-        return y, nc, 0.0
+        y, rows = apply_block_decode(lp, h, cfg, c, cur_pos, window=window)
+        return y, rows, 0.0
 
     new_cache = {}
-    if "dense_layers" in params:
-        x, c, _ = _scan_stack(params["dense_layers"], x, blk,
-                              caches=cache["dense"])
-        new_cache["dense"] = c
-    if "moe_layers" in params:
-        x, c, _ = _scan_stack(params["moe_layers"], x, blk,
-                              caches=cache["moe"])
-        new_cache["moe"] = c
+    for name, stack in (("dense", "dense_layers"), ("moe", "moe_layers")):
+        if stack in params:
+            x, rows, _ = _scan_stack(params[stack], x, blk,
+                                     caches=cache[name])
+            new_cache[name] = attn.write_decode_rows(cache[name], rows,
+                                                     cur_pos)
     x = apply_norm(params["final_norm"], x, cfg)
     return x, new_cache
 
@@ -255,19 +259,21 @@ def ssm_backbone_decode(params, x, cfg: ModelConfig, cache, cur_pos, *,
         x, c, _ = _scan_stack(params["layers"], x, blk, caches=cache["ssm"])
         new_cache["ssm"] = c
     else:
-        ssm_caches, attn_caches = [], []
+        ssm_caches, attn_rows = [], []
         for gi, (lo, hi) in enumerate(_hybrid_groups(cfg)):
             seg = jax.tree.map(lambda a: a[lo:hi], params["layers"])
             cseg = jax.tree.map(lambda a: a[lo:hi], cache["ssm"])
             x, c, _ = _scan_stack(seg, x, blk, caches=cseg)
             ssm_caches.append(c)
             ac = jax.tree.map(lambda a: a[gi], cache["attn"])
-            x, nac = apply_block_decode(params["shared_attn"], x, cfg, ac,
-                                        cur_pos, window=window)
-            attn_caches.append(nac)
+            x, rows = apply_block_decode(params["shared_attn"], x, cfg, ac,
+                                         cur_pos, window=window)
+            attn_rows.append(rows)
         new_cache["ssm"] = jax.tree.map(
             lambda *xs: jnp.concatenate(xs, axis=0), *ssm_caches)
-        new_cache["attn"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs, axis=0), *attn_caches)
+        new_cache["attn"] = attn.write_decode_rows(
+            cache["attn"],
+            jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *attn_rows),
+            cur_pos)
     x = apply_norm(params["final_norm"], x, cfg)
     return x, new_cache
