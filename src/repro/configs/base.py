@@ -34,6 +34,7 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0                # 0 -> d_model // num_heads
+    attn_scale: float = 0.0          # softmax scale; 0 -> head_dim ** -0.5
     source: str = ""                 # citation for the config
 
     # --- norm / activation / embeddings -----------------------------------
@@ -65,8 +66,12 @@ class ModelConfig:
     ssm_headdim: int = 64
     ssm_expand: int = 2
     ssm_chunk: int = 256
-    attn_every: int = 0              # hybrid: 1 shared attn block per N
-                                     # mamba blocks (zamba2-style)
+    ssm_ngroups: int = 1             # B/C groups; heads split evenly
+    # --- hybrid (Zamba2): shared attention+MLP blocks between Mamba2 layers
+    hybrid_layer_ids: Tuple[int, ...] = ()   # Mamba2 layers whose input
+                                     # takes a shared block's output
+    num_mem_blocks: int = 0          # shared blocks, used in turn
+    adapter_rank: int = 0            # per-use LoRA on the shared MLP
 
     # --- encoder-decoder (whisper) ------------------------------------------
     is_encoder_decoder: bool = False
@@ -86,6 +91,9 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.use_mla and self.mla is None:
             object.__setattr__(self, "mla", MLAConfig())
+        # a list from JSON would make the config unhashable (a jit static)
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
 
     # ------------------------------------------------------------------ utils
     @property
@@ -104,6 +112,15 @@ class ModelConfig:
     @property
     def ssm_nheads(self) -> int:
         return self.ssm_d_inner // self.ssm_headdim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the causal conv: x, then B and C of every group."""
+        return self.ssm_d_inner + 2 * self.ssm_ngroups * self.ssm_state
+
+    @property
+    def softmax_scale(self) -> float:
+        return self.attn_scale or 1.0 / math.sqrt(self.head_dim)
 
     def param_count(self) -> int:
         """Analytic parameter count (for roofline MODEL_FLOPS = 6ND)."""
@@ -137,18 +154,28 @@ def _ffn_params(d_model: int, d_ff: int, act: str) -> int:
 
 
 def _ssm_params(cfg: ModelConfig) -> int:
-    d, di, ns, nh = cfg.d_model, cfg.ssm_d_inner, cfg.ssm_state, cfg.ssm_nheads
-    # in_proj -> [z, x, B, C, dt], out_proj, conv (ignored, small), A/D/dt_bias
-    p = d * (2 * di + 2 * ns + nh)
-    p += di * d
-    p += 2 * nh + nh
-    return p
+    d, di, nh = cfg.d_model, cfg.ssm_d_inner, cfg.ssm_nheads
+    conv = cfg.ssm_conv_dim
+    # in_proj -> [z, x, B, C, dt], conv weight and bias, A/D/dt_bias,
+    # gated norm, out_proj
+    return d * (di + conv + nh) + 5 * conv + 3 * nh + di + di * d
+
+
+def _shared_block_params(cfg: ModelConfig) -> int:
+    """One Zamba2 shared block: norm and attention over [h, x0] (2*d wide),
+    norm and gated MLP."""
+    d, a = cfg.d_model, cfg.num_heads * cfg.head_dim
+    return 2 * d + 3 * 2 * d * a + a * d + d + 3 * d * cfg.d_ff
+
+
+def _hybrid_use_params(cfg: ModelConfig) -> int:
+    """Each use of a shared block: its MLP adapter and its output linear."""
+    d = cfg.d_model
+    return cfg.adapter_rank * (d + 2 * cfg.d_ff) + d * d
 
 
 def _layer_params(cfg: ModelConfig, moe_layer: bool) -> int:
     p = 2 * cfg.d_model  # two norms
-    if cfg.family == "ssm" or (cfg.family == "hybrid" and True):
-        pass
     if moe_layer:
         ffn = (cfg.num_experts + cfg.num_shared_experts) * _ffn_params(
             cfg.d_model, cfg.moe_d_ff, cfg.act)
@@ -167,10 +194,9 @@ def _param_count(cfg: ModelConfig, active_only: bool) -> int:
         per = 2 * d + _ssm_params(cfg)
         total += cfg.num_layers * per
     elif cfg.family == "hybrid":
-        per = 2 * d + _ssm_params(cfg)
-        total += cfg.num_layers * per
-        # one shared attention+mlp block
-        total += _attn_params(cfg) + _ffn_params(d, cfg.d_ff, cfg.act) + 2 * d
+        total += cfg.num_layers * (d + _ssm_params(cfg)) + d
+        total += cfg.num_mem_blocks * _shared_block_params(cfg)
+        total += len(cfg.hybrid_layer_ids) * _hybrid_use_params(cfg)
     elif cfg.num_experts > 0:
         n_moe = cfg.num_layers - cfg.num_dense_layers
         dense = cfg.num_dense_layers * _layer_params(cfg, moe_layer=False)
@@ -235,8 +261,11 @@ def reduce_for_smoke(cfg: ModelConfig) -> ModelConfig:
     if cfg.ssm_state:
         kw.update(ssm_state=min(cfg.ssm_state, 32), ssm_headdim=16,
                   ssm_chunk=32)
-    if cfg.attn_every:
-        kw.update(attn_every=2)
+    if cfg.hybrid_layer_ids:
+        # both layers take a shared block: A then B, each use its adapter
+        hd = 2 * d_model // num_heads
+        kw.update(hybrid_layer_ids=(0, 1), adapter_rank=8, head_dim=hd,
+                  attn_scale=(hd / 2) ** -0.5)
     if cfg.is_encoder_decoder:
         kw.update(encoder_layers=2, encoder_seq=16)
     if cfg.num_patches:

@@ -315,7 +315,6 @@ def main(argv=None):
 # --------------------------------------------------------------------------
 
 def probe_variants(cfg: ModelConfig):
-    import math as _m
     if cfg.family == "audio":
         mk = lambda e, d: dataclasses.replace(cfg, encoder_layers=e,
                                               num_layers=d)
@@ -323,14 +322,17 @@ def probe_variants(cfg: ModelConfig):
                  (mk(1, 3), [1, 1, 3])],
                 [1, cfg.encoder_layers, cfg.num_layers])
     if cfg.family == "hybrid":
-        # G = ceil(L/k) is collinear with L at multiples of k, so two
-        # probes suffice; the min-norm lstsq solution is exact up to the
-        # ceil() fraction of one shared-attention block (<4% of a block).
-        k = cfg.attn_every
-        feats = lambda L: [1, L, _m.ceil(L / k)]
-        mk = lambda L: dataclasses.replace(cfg, num_layers=L)
-        return ([(mk(k), feats(k)), (mk(2 * k), feats(2 * k))],
-                feats(cfg.num_layers))
+        # cost = a + b * (Mamba2 layers) + c * (shared-block uses): three
+        # depths cut before the first use, after it and after the second
+        ids = cfg.hybrid_layer_ids
+
+        def mk(L):
+            kept = tuple(i for i in ids if i < L)
+            return (dataclasses.replace(cfg, num_layers=L,
+                                        hybrid_layer_ids=kept),
+                    [1, L, len(kept)])
+        return ([mk(ids[0]), mk(ids[0] + 1), mk(ids[1] + 1)],
+                [1, cfg.num_layers, len(ids)])
     if cfg.num_experts and cfg.num_dense_layers:
         mk = lambda d, m: dataclasses.replace(cfg, num_dense_layers=d,
                                               num_layers=d + m)

@@ -41,9 +41,12 @@ NEG_INF = -1e30
 # Init
 # --------------------------------------------------------------------------
 
-def init_attention(cfg: ModelConfig, key) -> Dict:
+def init_attention(cfg: ModelConfig, key, d_in: Optional[int] = None) -> Dict:
+    """``d_in``: width of the attention's input where it differs from
+    d_model, its output's (Zamba2's shared block attends over [h, x0])."""
     dt = jnp.dtype(cfg.dtype)
     d, hd = cfg.d_model, cfg.head_dim
+    di = d_in or d
     if cfg.use_mla:
         m = cfg.mla
         ks = jax.random.split(key, 6)
@@ -70,9 +73,9 @@ def init_attention(cfg: ModelConfig, key) -> Dict:
         return p
     ks = jax.random.split(key, 4)
     p = {
-        "wq": dense_init(ks[0], (d, cfg.num_heads * hd), ("embed", "qkv"), dtype=dt),
-        "wk": dense_init(ks[1], (d, cfg.num_kv_heads * hd), ("embed", "qkv"), dtype=dt),
-        "wv": dense_init(ks[2], (d, cfg.num_kv_heads * hd), ("embed", "qkv"), dtype=dt),
+        "wq": dense_init(ks[0], (di, cfg.num_heads * hd), ("embed", "qkv"), dtype=dt),
+        "wk": dense_init(ks[1], (di, cfg.num_kv_heads * hd), ("embed", "qkv"), dtype=dt),
+        "wv": dense_init(ks[2], (di, cfg.num_kv_heads * hd), ("embed", "qkv"), dtype=dt),
         "wo": dense_init(ks[3], (cfg.num_heads * hd, d), ("qkv", "embed"), dtype=dt),
     }
     if cfg.use_qkv_bias:
@@ -210,7 +213,7 @@ def attention_forward(params, x, cfg: ModelConfig, positions,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.softmax_scale
     if causal and kv_x is None:
         kpos = positions
         out = blockwise_attention(q, k, v, positions, kpos,
@@ -310,20 +313,31 @@ def _attend_decode(q, k, v, k_new, v_new, mask, scale):
     return out.reshape(B, 1, H, v.shape[-1]).astype(q.dtype)
 
 
-def write_decode_rows(cache: Dict, rows: Dict, cur_pos) -> Dict:
+def write_decode_rows(cache: Dict, rows: Dict, cur_pos, *,
+                      per_slot: bool = False) -> Dict:
     """Write each layer's new-token rows into a stacked decode cache.
 
     cache leaves are (L, B, W, ...); rows hold the same keys but "pos",
     each (L, B, ...) in the cache's dtype.  Row b goes to slot
     cur_pos[b] % W of every layer, and pos there becomes cur_pos[b];
-    nothing else changes.  With the cache donated the update is in place
-    (one scatter over layer, row and slot, so the window it writes is the
-    cache's minor dimensions)."""
+    nothing else changes.  With the cache donated the update is in place:
+    one scatter over layer, row and slot, so the window it writes is the
+    cache's minor dimensions.  ``per_slot`` writes instead with one
+    dynamic-update-slice per row b, which stays in place whatever the
+    cache's device layout: a TPU lays a cache with head_dim 224 out with
+    the positions minor, and there a scatter re-lays the whole cache."""
     L, B, W = cache["pos"].shape
     slot = jnp.mod(cur_pos, W)
     pos = jnp.broadcast_to(cur_pos.astype(jnp.int32), (L, B))
     new = dict(rows, pos=pos)
-    if flags.WHERE_CACHE_UPDATE:
+    if per_slot:
+        def put(c, r):
+            for b in range(B):
+                start = (0, b, slot[b]) + (0,) * (c.ndim - 3)
+                c = jax.lax.dynamic_update_slice(
+                    c, r[:, b:b + 1, None], start)
+            return c
+    elif flags.WHERE_CACHE_UPDATE:
         sel = (jnp.arange(W, dtype=jnp.int32)[None, :]
                == slot[:, None])                         # (B, W)
 
@@ -372,7 +386,7 @@ def attention_decode(params, x, cfg: ModelConfig, cache: Dict,
             "v": v[:, 0].astype(cache["v"].dtype)}
     mask = _decode_mask(cache["pos"], cur_pos, window or cfg.sliding_window)
     out = _attend_decode(q, cache["k"], cache["v"], rows["k"], rows["v"],
-                         mask, 1.0 / math.sqrt(hd))
+                         mask, cfg.softmax_scale)
     y = out.reshape(B, 1, cfg.num_heads * hd) @ params["wo"]
     return shard(y, "batch", None, "embed_act"), rows
 
@@ -387,7 +401,7 @@ def cross_attention_decode(params, x, cfg: ModelConfig, cross_cache: Dict):
     q = q.reshape(B, 1, cfg.num_heads, hd)
     mask = jnp.ones((B, 1, cross_cache["k"].shape[1]), bool)
     out = _attend(q, cross_cache["k"], cross_cache["v"], mask,
-                  1.0 / math.sqrt(hd))
+                  cfg.softmax_scale)
     y = out.reshape(B, 1, cfg.num_heads * hd) @ params["wo"]
     return shard(y, "batch", None, "embed_act")
 

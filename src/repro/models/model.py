@@ -133,11 +133,14 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_seq: int,
         return {"ssm": _tile(ssm_mod.init_ssm_cache(cfg, batch),
                              cfg.num_layers)}
     if cfg.family == "hybrid":
-        n_groups = len(tfm._hybrid_groups(cfg))
+        # a stacked state per group of Mamba2 layers, and one KV cache per
+        # shared-block use
+        one = ssm_mod.init_ssm_cache(cfg, batch)
         return {
-            "ssm": _tile(ssm_mod.init_ssm_cache(cfg, batch), cfg.num_layers),
+            "ssm": [_tile(one, hi - lo)
+                    for lo, hi, _ in tfm.hybrid_groups(cfg)],
             "attn": _tile(attn.init_cache(cfg, batch, max_seq, window),
-                          n_groups),
+                          len(cfg.hybrid_layer_ids)),
         }
     one = attn.init_cache(cfg, batch, max_seq, window)
     out = {}

@@ -22,8 +22,9 @@ from repro.models import attention as attn
 from repro.models import flags
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
-from repro.models.layers import (apply_mlp, apply_norm, embed_tokens,
-                                 init_embedding, init_mlp, init_norm, lm_head)
+from repro.models.layers import (apply_mlp, apply_norm, dense_init,
+                                 embed_tokens, init_embedding, init_mlp,
+                                 init_norm, lm_head)
 
 
 def stack_init(init_fn, key, n: int, axis_name: Optional[str] = None):
@@ -99,14 +100,113 @@ def init_ssm_block(cfg: ModelConfig, key) -> Dict:
     return {"norm": init_norm(cfg), "mixer": ssm_mod.init_ssm(cfg, key)}
 
 
-def apply_ssm_block(params, x, cfg, *, return_cache=False, cache=None):
-    h = apply_norm(params["norm"], x, cfg)
+def apply_ssm_block(params, x, cfg, *, t=None, return_cache=False,
+                    cache=None):
+    """x + Mamba2(norm(x)), or x + Mamba2(norm(x + t)) where a Zamba2
+    shared block's output t enters the layer (not the residual)."""
+    h = apply_norm(params["norm"], x if t is None else x + t, cfg)
     if cache is None:
         y, new_cache = ssm_mod.ssm_forward(params["mixer"], h, cfg,
                                            return_cache=return_cache)
     else:
         y, new_cache = ssm_mod.ssm_decode(params["mixer"], h, cfg, cache)
     return x + y, new_cache
+
+
+def _ssm_scan(layers, x, cfg, *, t=None, return_cache=False,
+              remat=False):
+    """Full-sequence scan over a stack of Mamba2 layers; ``t`` enters the
+    first layer only.  Returns (x, per-layer caches stacked | None)."""
+    def step(carry, lp):
+        h, tc = carry
+        y, c = apply_ssm_block(lp, h, cfg, t=tc, return_cache=return_cache)
+        return (y, None if tc is None else jnp.zeros_like(tc)), \
+            (c if return_cache else 0)
+
+    if remat:
+        step = jax.checkpoint(
+            step, policy=jax.checkpoint_policies.nothing_saveable)
+    (x, _), caches = jax.lax.scan(step, (x, t), layers,
+                                  unroll=flags.scan_unroll())
+    return x, (caches if return_cache else None)
+
+
+def _ssm_scan_decode(layers, x, cfg, state, *, t=None):
+    """One decode step over a stack of Mamba2 layers.  The stacked state
+    (conv and SSM, leading layer axis) rides in the scan's carry: each
+    layer reads its slice and writes its new one in place, so a donated
+    state is neither copied nor emitted a second time."""
+    def step(carry, inp):
+        h, tc, st = carry
+        lp, i = inp
+        mine = jax.tree.map(lambda a: a[i], st)
+        y, new = apply_ssm_block(lp, h, cfg, t=tc, cache=mine)
+        st = jax.tree.map(
+            lambda a, b: jax.lax.dynamic_update_index_in_dim(
+                a, b.astype(a.dtype), i, 0), st, new)
+        return (y, None if tc is None else jnp.zeros_like(tc), st), None
+
+    n = jax.tree.leaves(layers)[0].shape[0]
+    (x, _, state), _ = jax.lax.scan(
+        step, (x, t, state), (layers, jnp.arange(n)),
+        unroll=flags.scan_unroll())
+    return x, state
+
+
+# --------------------------------------------------------------------------
+# Zamba2 shared block
+# --------------------------------------------------------------------------
+
+def init_shared_block(cfg: ModelConfig, key) -> Dict:
+    """One of Zamba2's ``num_mem_blocks`` shared attention+MLP blocks."""
+    d, dt = cfg.d_model, jnp.dtype(cfg.dtype)
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {
+        "norm_in": {"scale": P(jnp.ones((2 * d,), jnp.float32),
+                               ("embed_act",))},
+        "attn": attn.init_attention(cfg, k1, d_in=2 * d),
+        "norm_ff": init_norm(cfg),
+        "mlp": {"w_gate_up": dense_init(k2, (d, 2 * cfg.d_ff),
+                                        ("embed", "mlp"), dtype=dt),
+                "w_down": dense_init(k3, (cfg.d_ff, d), ("mlp", "embed"),
+                                     dtype=dt)},
+    }
+
+
+def init_hybrid_use(cfg: ModelConfig, key) -> Dict:
+    """What each use of a shared block owns: the LoRA on its MLP's
+    gate/up projection and the linear after the block."""
+    d, dt = cfg.d_model, jnp.dtype(cfg.dtype)
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {"lora_a": dense_init(k1, (d, cfg.adapter_rank),
+                                 ("embed", None), dtype=dt),
+            "lora_b": dense_init(k2, (cfg.adapter_rank, 2 * cfg.d_ff),
+                                 (None, "mlp"), dtype=dt),
+            "linear": dense_init(k3, (d, d), ("embed", None), dtype=dt)}
+
+
+def apply_shared_block(block, use, x, x0, cfg: ModelConfig, positions, *,
+                       return_cache: bool = False, cache=None):
+    """Zamba2's shared block, with no residual: [h, x0] through the norm,
+    the attention, the norm and the MLP (gelu(g) * up, from [g, up] =
+    m W_gu + (m A_use) B_use), then W_down and the use's linear.  Returns
+    (t, KV) for the next Mamba2 layer: the block's attention cache over
+    the sequence, or with ``cache`` (this use's, only read; ``positions``
+    then cur_pos) one decode step's new KV rows."""
+    with jax.named_scope("zamba2.shared"):
+        u = apply_norm(block["norm_in"], jnp.concatenate([x, x0], -1), cfg)
+        if cache is None:
+            o, kv = attn.attention_forward(block["attn"], u, cfg, positions,
+                                           return_cache=return_cache)
+        else:
+            o, kv = attn.attention_decode(block["attn"], u, cfg, cache,
+                                          positions)
+        m = apply_norm(block["norm_ff"], o, cfg)
+        mp = block["mlp"]
+        gu = m @ mp["w_gate_up"] + (m @ use["lora_a"]) @ use["lora_b"]
+        g, up = jnp.split(gu, 2, axis=-1)
+        h = jax.nn.gelu(g, approximate=False) * up
+        return (h @ mp["w_down"]) @ use["linear"], kv
 
 
 # --------------------------------------------------------------------------
@@ -198,82 +298,90 @@ def backbone_decode(params, x, cfg: ModelConfig, cache, cur_pos, *,
 # SSM / hybrid LM
 # --------------------------------------------------------------------------
 
+def hybrid_groups(cfg: ModelConfig):
+    """The hybrid's Mamba2 layers as scanned groups: (lo, hi, use) for
+    layers lo..hi-1, where ``use`` is the index of the shared-block use
+    whose output enters layer lo (None for leading plain layers)."""
+    ids = cfg.hybrid_layer_ids
+    bounds = sorted({0, *ids}) + [cfg.num_layers]
+    return [(lo, hi, ids.index(lo) if lo in ids else None)
+            for lo, hi in zip(bounds, bounds[1:])]
+
+
 def init_ssm_lm(cfg: ModelConfig, key) -> Dict:
-    ke, kl, ka = jax.random.split(key, 3)
-    p = {"embed": init_embedding(cfg, ke),
-         "final_norm": init_norm(cfg),
-         "layers": stack_init(lambda k: init_ssm_block(cfg, k), kl,
-                              cfg.num_layers)}
-    if cfg.attn_every:  # hybrid: one weight-shared attention block
-        p["shared_attn"] = init_block(cfg, ka, moe_layer=False)
+    """SSM: one stack of Mamba2 layers.  Hybrid: one stack per group of
+    ``hybrid_groups``, made apart at init so that no program slices a
+    group out of a whole-depth stack; the shared blocks; one adapter and
+    linear per use."""
+    ke, kl, ka, ku = jax.random.split(key, 4)
+    p = {"embed": init_embedding(cfg, ke), "final_norm": init_norm(cfg)}
+    block = lambda k: init_ssm_block(cfg, k)
+    if cfg.family == "ssm":
+        p["layers"] = stack_init(block, kl, cfg.num_layers)
+        return p
+    groups = hybrid_groups(cfg)
+    p["layers"] = [stack_init(block, k, hi - lo) for (lo, hi, _), k in
+                   zip(groups, jax.random.split(kl, len(groups)))]
+    p["shared"] = [init_shared_block(cfg, k) for k in
+                   jax.random.split(ka, cfg.num_mem_blocks)]
+    p["uses"] = [init_hybrid_use(cfg, k) for k in
+                 jax.random.split(ku, len(cfg.hybrid_layer_ids))]
     return p
-
-
-def _hybrid_groups(cfg: ModelConfig):
-    n, k = cfg.num_layers, cfg.attn_every
-    bounds = []
-    i = 0
-    while i < n:
-        bounds.append((i, min(i + k, n)))
-        i += k
-    return bounds
 
 
 def ssm_backbone_forward(params, x, cfg: ModelConfig, positions, *,
                          return_cache: bool = False, remat: bool = False,
                          window: Optional[int] = None):
-    def blk(lp, h):
-        y, c = apply_ssm_block(lp, h, cfg, return_cache=return_cache)
-        return y, (c if return_cache else 0), 0.0
-
-    caches: Dict[str, Any] = {}
-    if not cfg.attn_every:
-        x, c, _ = _scan_stack(params["layers"], x, blk, remat=remat)
-        caches["ssm"] = c
-    else:
-        ssm_caches, attn_caches = [], []
-        for (lo, hi) in _hybrid_groups(cfg):
-            seg = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-            x, c, _ = _scan_stack(seg, x, blk, remat=remat)
-            ssm_caches.append(c)
-            x, ac, _ = apply_block(params["shared_attn"], x, cfg, positions,
-                                   window=window, return_cache=return_cache)
-            attn_caches.append(ac)
-        if return_cache:
-            caches["ssm"] = jax.tree.map(
-                lambda *xs: jnp.concatenate(xs, axis=0), *ssm_caches)
-            caches["attn"] = jax.tree.map(
-                lambda *xs: jnp.stack(xs, axis=0), *attn_caches)
+    """SSM: one scan.  Hybrid (Zamba2): per group, the shared block (block
+    use % num_mem_blocks, over [h, x0]) and then the group's scan, whose
+    first layer takes the block's output."""
+    if cfg.family == "ssm":
+        x, c = _ssm_scan(params["layers"], x, cfg,
+                         return_cache=return_cache, remat=remat)
+        x = apply_norm(params["final_norm"], x, cfg)
+        return x, ({"ssm": c} if return_cache else None), 0.0
+    x0 = x
+    ssm_caches, kv = [], []
+    for (_, _, use), layers in zip(hybrid_groups(cfg), params["layers"]):
+        t = None
+        if use is not None:
+            t, kc = apply_shared_block(
+                params["shared"][use % cfg.num_mem_blocks],
+                params["uses"][use], x, x0, cfg, positions,
+                return_cache=return_cache)
+            kv.append(kc)
+        x, c = _ssm_scan(layers, x, cfg, t=t, return_cache=return_cache,
+                         remat=remat)
+        ssm_caches.append(c)
     x = apply_norm(params["final_norm"], x, cfg)
-    return x, (caches if return_cache else None), 0.0
+    if not return_cache:
+        return x, None, 0.0
+    return x, {"ssm": ssm_caches,
+               "attn": jax.tree.map(lambda *a: jnp.stack(a), *kv)}, 0.0
 
 
 def ssm_backbone_decode(params, x, cfg: ModelConfig, cache, cur_pos, *,
                         window: Optional[int] = None):
-    def blk(lp, h, c):
-        y, nc = apply_ssm_block(lp, h, cfg, cache=c)
-        return y, nc, 0.0
-
-    new_cache: Dict[str, Any] = {}
-    if not cfg.attn_every:
-        x, c, _ = _scan_stack(params["layers"], x, blk, caches=cache["ssm"])
-        new_cache["ssm"] = c
-    else:
-        ssm_caches, attn_rows = [], []
-        for gi, (lo, hi) in enumerate(_hybrid_groups(cfg)):
-            seg = jax.tree.map(lambda a: a[lo:hi], params["layers"])
-            cseg = jax.tree.map(lambda a: a[lo:hi], cache["ssm"])
-            x, c, _ = _scan_stack(seg, x, blk, caches=cseg)
-            ssm_caches.append(c)
-            ac = jax.tree.map(lambda a: a[gi], cache["attn"])
-            x, rows = apply_block_decode(params["shared_attn"], x, cfg, ac,
-                                         cur_pos, window=window)
-            attn_rows.append(rows)
-        new_cache["ssm"] = jax.tree.map(
-            lambda *xs: jnp.concatenate(xs, axis=0), *ssm_caches)
-        new_cache["attn"] = attn.write_decode_rows(
-            cache["attn"],
-            jax.tree.map(lambda *xs: jnp.stack(xs, axis=0), *attn_rows),
-            cur_pos)
-    x = apply_norm(params["final_norm"], x, cfg)
-    return x, new_cache
+    """One decode step.  Each group's state is updated in place in its
+    scan; the shared blocks only read their uses' KV caches, and one
+    write after all groups puts every use's new rows in."""
+    if cfg.family == "ssm":
+        x, st = _ssm_scan_decode(params["layers"], x, cfg, cache["ssm"])
+        return apply_norm(params["final_norm"], x, cfg), {"ssm": st}
+    x0 = x
+    states, rows = [], []
+    for (_, _, use), layers, st in zip(hybrid_groups(cfg), params["layers"],
+                                       cache["ssm"]):
+        t = None
+        if use is not None:
+            kv = jax.tree.map(lambda a: a[use], cache["attn"])
+            t, r = apply_shared_block(
+                params["shared"][use % cfg.num_mem_blocks],
+                params["uses"][use], x, x0, cfg, cur_pos, cache=kv)
+            rows.append(r)
+        x, st = _ssm_scan_decode(layers, x, cfg, st, t=t)
+        states.append(st)
+    new_cache = {"ssm": states, "attn": attn.write_decode_rows(
+        cache["attn"], jax.tree.map(lambda *a: jnp.stack(a), *rows),
+        cur_pos, per_slot=True)}
+    return apply_norm(params["final_norm"], x, cfg), new_cache

@@ -19,7 +19,8 @@ which a profiler session puts on the device trace's clock::
         engine.prefill       one per admitted request; args: rid, tokens
           engine.prefill.launch   prompt upload and prefill launch
           engine.prefill.pull     first-token argmax and pull (waits for it)
-          engine.slot_write       slot-write launch
+          engine.slot_write       slot-write launch; args: state_bytes,
+                                  kv_bytes (what the write moves)
       engine.decode          args: rows
         engine.decode.inputs      token and position build and uploads
         engine.decode.launch      decode launch
@@ -178,7 +179,7 @@ class ServingEngine:
                 req.token_ns.append(time.perf_counter_ns())
             offset = (batch["patches"].shape[1]
                       if self.cfg.family == "vlm" else 0)
-            with span("engine.slot_write"):
+            with span("engine.slot_write", **cache_bytes(pcache)):
                 self.cache = self._write(self.cache, pcache, jnp.int32(slot))
             st = self.slots[slot]
             st.req = req
@@ -238,6 +239,22 @@ class ServingEngine:
     def run(self, max_steps: int = 10_000) -> None:
         while self.has_work and self.step_count < max_steps:
             self.step()
+
+
+#: cache leaves that hold a recurrent state (the rest hold KV rows)
+STATE_LEAVES = ("conv", "ssm")
+
+
+def cache_bytes(cache) -> Dict[str, int]:
+    """Bytes of a cache tree by leaf kind: ``state_bytes`` of the
+    recurrent state (SSM and conv), ``kv_bytes`` of the rest (keys,
+    values, positions)."""
+    out = {"state_bytes": 0, "kv_bytes": 0}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
+        kind = getattr(path[-1], "key", None)
+        out["state_bytes" if kind in STATE_LEAVES else "kv_bytes"] += \
+            leaf.nbytes
+    return out
 
 
 def _prefill_last(cfg: ModelConfig, params, batch):

@@ -88,8 +88,8 @@ def test_ssd_kernel_used_by_model():
     x = jax.random.normal(ks[0], (b, l, h, p), jnp.float32)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h)))
     A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
-    Bm = jax.random.normal(ks[3], (b, l, n), jnp.float32)
-    Cm = jax.random.normal(ks[0], (b, l, n), jnp.float32)
+    Bm = jax.random.normal(ks[3], (b, l, 1, n), jnp.float32)
+    Cm = jax.random.normal(ks[0], (b, l, 1, n), jnp.float32)
     y1, f1 = ssd_chunked(x, dt, A, Bm, Cm, chunk, use_kernel=False)
     y2, f2 = ssd_chunked(x, dt, A, Bm, Cm, chunk, use_kernel=True)
     np.testing.assert_allclose(y1, y2, atol=1e-4, rtol=1e-4)

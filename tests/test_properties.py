@@ -40,8 +40,8 @@ def test_ssd_chunked_equals_stepwise(b, l, h, chunk):
     x = jax.random.normal(ks[0], (b, l, h, p))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h)))
     A = -jnp.exp(0.3 * jax.random.normal(ks[2], (h,)))
-    Bm = jax.random.normal(ks[3], (b, l, n))
-    Cm = jax.random.normal(ks[4], (b, l, n))
+    Bm = jax.random.normal(ks[3], (b, l, 1, n))
+    Cm = jax.random.normal(ks[4], (b, l, 1, n))
     y_chunk, final_chunk = ssd_chunked(x, dt, A, Bm, Cm, chunk)
     # stepwise reference
     state = jnp.zeros((b, h, p, n))
