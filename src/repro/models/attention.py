@@ -19,7 +19,9 @@ Prefill uses a q-block lazy-flash (lax.scan over query blocks) so the
 MLA form / direct GQA reduction.  A decode step only reads each layer's
 cache: every layer returns its new token's rows, and the caller writes
 all layers' rows into the stacked cache at once (``write_decode_rows``),
-so the cache is neither copied nor re-emitted by the layer scan.
+so the cache is neither copied nor re-emitted by the layer scan.  A
+decode of B rows serves cache rows 0..B-1: it reads and writes only those,
+whatever the cache's batch, and leaves the rest as they are.
 """
 from __future__ import annotations
 
@@ -317,8 +319,9 @@ def write_decode_rows(cache: Dict, rows: Dict, cur_pos, *,
                       per_slot: bool = False) -> Dict:
     """Write each layer's new-token rows into a stacked decode cache.
 
-    cache leaves are (L, B, W, ...); rows hold the same keys but "pos",
-    each (L, B, ...) in the cache's dtype.  Row b goes to slot
+    cache leaves are (L, Bc, W, ...); rows hold the same keys but "pos",
+    each (L, B, ...) in the cache's dtype, for cache rows 0..B-1 (B <= Bc;
+    B == Bc under ``flags.WHERE_CACHE_UPDATE``).  Row b goes to slot
     cur_pos[b] % W of every layer, and pos there becomes cur_pos[b];
     nothing else changes.  With the cache donated the update is in place:
     one scatter over layer, row and slot, so the window it writes is the
@@ -326,7 +329,8 @@ def write_decode_rows(cache: Dict, rows: Dict, cur_pos, *,
     dynamic-update-slice per row b, which stays in place whatever the
     cache's device layout: a TPU lays a cache with head_dim 224 out with
     the positions minor, and there a scatter re-lays the whole cache."""
-    L, B, W = cache["pos"].shape
+    L, _, W = cache["pos"].shape
+    B = cur_pos.shape[0]
     slot = jnp.mod(cur_pos, W)
     pos = jnp.broadcast_to(cur_pos.astype(jnp.int32), (L, B))
     new = dict(rows, pos=pos)
@@ -358,9 +362,10 @@ def attention_decode(params, x, cfg: ModelConfig, cache: Dict,
                      window: Optional[int] = None):
     """x: (B, 1, D); cur_pos: (B,) absolute position of the new token.
 
-    ``cache`` (one layer) is only read.  Returns (y, rows): the new
-    token's cache rows, {"k", "v"} each (B, Hkv, hd), for
-    ``write_decode_rows`` to put at slot cur_pos % W once all layers ran.
+    ``cache`` (one layer) is only read, and only its rows 0..B-1.  Returns
+    (y, rows): the new token's cache rows, {"k", "v"} each (B, Hkv, hd),
+    for ``write_decode_rows`` to put at slot cur_pos % W once all layers
+    ran.
     """
     if cfg.use_mla:
         return _mla_decode(params, x, cfg, cache, cur_pos)
@@ -382,26 +387,27 @@ def attention_decode(params, x, cfg: ModelConfig, cache: Dict,
         q = apply_rope(q, cur_pos[:, None], cfg.rope_theta)
         k = apply_rope(k, cur_pos[:, None], cfg.rope_theta)
 
-    rows = {"k": k[:, 0].astype(cache["k"].dtype),
-            "v": v[:, 0].astype(cache["v"].dtype)}
-    mask = _decode_mask(cache["pos"], cur_pos, window or cfg.sliding_window)
-    out = _attend_decode(q, cache["k"], cache["v"], rows["k"], rows["v"],
-                         mask, cfg.softmax_scale)
+    kc, vc, pc = cache["k"][:B], cache["v"][:B], cache["pos"][:B]
+    rows = {"k": k[:, 0].astype(kc.dtype), "v": v[:, 0].astype(vc.dtype)}
+    mask = _decode_mask(pc, cur_pos, window or cfg.sliding_window)
+    out = _attend_decode(q, kc, vc, rows["k"], rows["v"], mask,
+                         cfg.softmax_scale)
     y = out.reshape(B, 1, cfg.num_heads * hd) @ params["wo"]
     return shard(y, "batch", None, "embed_act"), rows
 
 
 def cross_attention_decode(params, x, cfg: ModelConfig, cross_cache: Dict):
-    """Decoder cross-attn against a fixed, precomputed encoder KV cache."""
+    """Decoder cross-attn against a fixed, precomputed encoder KV cache,
+    of which a decode of B rows reads rows 0..B-1."""
     B = x.shape[0]
     hd = cfg.head_dim
     q = (x @ params["wq"])
     if cfg.use_qkv_bias:
         q = q + params["bq"]
     q = q.reshape(B, 1, cfg.num_heads, hd)
-    mask = jnp.ones((B, 1, cross_cache["k"].shape[1]), bool)
-    out = _attend(q, cross_cache["k"], cross_cache["v"], mask,
-                  cfg.softmax_scale)
+    k, v = cross_cache["k"][:B], cross_cache["v"][:B]
+    mask = jnp.ones((B, 1, k.shape[1]), bool)
+    out = _attend(q, k, v, mask, cfg.softmax_scale)
     y = out.reshape(B, 1, cfg.num_heads * hd) @ params["wo"]
     return shard(y, "batch", None, "embed_act")
 
@@ -409,13 +415,15 @@ def cross_attention_decode(params, x, cfg: ModelConfig, cross_cache: Dict):
 def _mla_decode(params, x, cfg: ModelConfig, cache: Dict, cur_pos):
     """Absorbed-matrix MLA decode: attention runs in the latent space.
 
-    Same contract as ``attention_decode``: the cache is only read, and the
-    new token's rows {"ckv": (B, lora), "krope": (B, rope_dim)} return."""
+    Same contract as ``attention_decode``: the cache's rows 0..B-1 are only
+    read, and the new token's rows {"ckv": (B, lora), "krope": (B,
+    rope_dim)} return."""
     m = cfg.mla
     B = x.shape[0]
     H = cfg.num_heads
     nope, rope_d, vd = m.qk_nope_head_dim, m.qk_rope_head_dim, m.v_head_dim
     lora = m.kv_lora_rank
+    cache = {name: a[:B] for name, a in cache.items()}
 
     q_lat = apply_norm(params["q_norm"], x @ params["wq_a"], cfg)
     q = (q_lat @ params["wq_b"]).reshape(B, 1, H, nope + rope_d)

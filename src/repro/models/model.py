@@ -89,7 +89,11 @@ def forward(cfg: ModelConfig, params, batch: Dict, *,
 
 def decode_step(cfg: ModelConfig, params, tokens, cache, cur_pos, *,
                 window: Optional[int] = None):
-    """tokens: (B, 1); cur_pos: (B,).  Returns (logits, new_cache)."""
+    """tokens: (B, 1); cur_pos: (B,).  Returns (logits, new_cache).
+
+    B may be less than the cache's batch: the step serves cache rows
+    0..B-1, reads and writes only those, and leaves every other row of
+    every cache leaf as it is.  At the cache's batch nothing is sliced."""
     if cfg.family == "audio":
         x, new_self = encdec_mod.decoder_decode(
             params, tokens, cfg, cache["self"], cache["cross"], cur_pos)

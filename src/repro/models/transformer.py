@@ -135,15 +135,18 @@ def _ssm_scan_decode(layers, x, cfg, state, *, t=None):
     """One decode step over a stack of Mamba2 layers.  The stacked state
     (conv and SSM, leading layer axis) rides in the scan's carry: each
     layer reads its slice and writes its new one in place, so a donated
-    state is neither copied nor emitted a second time."""
+    state is neither copied nor emitted a second time.  A decode of b rows
+    (x's) reads and writes only rows 0..b-1 of each layer's slice."""
+    b = x.shape[0]
+
     def step(carry, inp):
         h, tc, st = carry
         lp, i = inp
-        mine = jax.tree.map(lambda a: a[i], st)
+        mine = jax.tree.map(lambda a: a[i, :b], st)
         y, new = apply_ssm_block(lp, h, cfg, t=tc, cache=mine)
         st = jax.tree.map(
-            lambda a, b: jax.lax.dynamic_update_index_in_dim(
-                a, b.astype(a.dtype), i, 0), st, new)
+            lambda a, u: jax.lax.dynamic_update_index_in_dim(
+                a, u.astype(a.dtype), i, 0), st, new)
         return (y, None if tc is None else jnp.zeros_like(tc), st), None
 
     n = jax.tree.leaves(layers)[0].shape[0]

@@ -21,7 +21,7 @@ which a profiler session puts on the device trace's clock::
           engine.prefill.pull     first-token argmax and pull (waits for it)
           engine.slot_write       slot-write launch; args: state_bytes,
                                   kv_bytes (what the write moves)
-      engine.decode          args: rows
+      engine.decode          args: rows (live), batch (rows launched)
         engine.decode.inputs      token and position build and uploads
         engine.decode.launch      decode launch
         engine.decode.pull        argmax and pull (waits for the decode)
@@ -30,6 +30,12 @@ which a profiler session puts on the device trace's clock::
 Every request carries host timestamps (``time.perf_counter_ns``), always
 on: ``submit_ns``, ``admit_ns`` (its prefill starts) and one ``token_ns``
 per token, taken when the token reached the host.
+
+A decode launches a prefix of the slots: the smallest rung of
+``decode_ladder(max_batch)`` that holds the highest live slot. The model
+then reads and writes only those rows of the cache, so a lightly loaded
+engine moves little more than the weights. Slots are filled lowest first.
+Every rung's decode program is built when the engine is.
 """
 from __future__ import annotations
 
@@ -118,6 +124,9 @@ class ServingEngine:
         #: per step with an active slot
         self.prefill_count = 0
         self.decode_count = 0
+        #: rows the decodes launched, live or dead (the ``batch`` args)
+        self.decode_rows_launched = 0
+        self.ladder = decode_ladder(max_batch)
 
         self._prefill = jax.jit(_prefill_last, static_argnums=0)
         # the decode cache is donated into every step and every slot
@@ -126,6 +135,17 @@ class ServingEngine:
             lambda p, toks, cache, pos: model_mod.decode_step(
                 cfg, p, toks, cache, pos), donate_argnums=(2,))
         self._write = jax.jit(_write_slot, donate_argnums=(0,))
+        self._build_decodes()
+
+    def _build_decodes(self) -> None:
+        """One decode and its token pull per rung on the fresh cache, whose
+        rows are all dead (an admission rewrites a slot's rows whole), so
+        that no step builds or loads a program for them."""
+        for b in self.ladder:
+            logits, self.cache = self._decode(
+                self.params, jnp.asarray(np.zeros((b, 1), np.int32)),
+                self.cache, jnp.asarray(np.zeros((b,), np.int32)))
+            _next_tokens(logits)
 
     # ---------------------------------------------------------------- intake
     def submit(self, req: ServeRequest) -> None:
@@ -205,15 +225,23 @@ class ServingEngine:
             self._admit()
             rows = self.active
             if rows:
-                with span("engine.decode", rows=rows):
-                    self._decode_one()
+                b = self.decode_batch()
+                with span("engine.decode", rows=rows, batch=b):
+                    self._decode_one(b)
 
-    def _decode_one(self) -> None:
-        """Decode one token for every active slot."""
+    def decode_batch(self) -> int:
+        """Rows the next decode launches: the smallest rung that holds the
+        highest live slot."""
+        top = max(i for i, s in enumerate(self.slots) if s.req is not None)
+        return next(b for b in self.ladder if b > top)
+
+    def _decode_one(self, b: int) -> None:
+        """Decode one token for every active slot, all among the first
+        ``b``."""
         with span("engine.decode.inputs"):
-            toks = np.zeros((self.max_batch, 1), np.int32)
-            pos = np.zeros((self.max_batch,), np.int32)
-            for i, s in enumerate(self.slots):
+            toks = np.zeros((b, 1), np.int32)
+            pos = np.zeros((b,), np.int32)
+            for i, s in enumerate(self.slots[:b]):
                 if s.req is not None:
                     toks[i, 0] = s.req.tokens[-1]
                     pos[i] = s.pos
@@ -222,11 +250,12 @@ class ServingEngine:
             logits, self.cache = self._decode(self.params, toks, self.cache,
                                               pos)
             self.decode_count += 1
+            self.decode_rows_launched += b
         with span("engine.decode.pull"):
-            nxt = np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+            nxt = _next_tokens(logits)
             t = time.perf_counter_ns()
         with span("engine.decode.emit"):
-            for i, s in enumerate(self.slots):
+            for i, s in enumerate(self.slots[:b]):
                 if s.req is None:
                     continue
                 s.req.tokens.append(int(nxt[i]))
@@ -239,6 +268,22 @@ class ServingEngine:
     def run(self, max_steps: int = 10_000) -> None:
         while self.has_work and self.step_count < max_steps:
             self.step()
+
+
+def _next_tokens(logits) -> np.ndarray:
+    """Each decoded row's greedy token, on the host (waits for the
+    decode)."""
+    return np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+
+
+def decode_ladder(max_batch: int) -> List[int]:
+    """The decode's batch sizes: powers of two times max(1, max_batch // 4)
+    below max_batch, then max_batch (16: 4, 8, 16; 4: 1, 2, 4)."""
+    rung, out = max(1, max_batch // 4), []
+    while rung < max_batch:
+        out.append(rung)
+        rung *= 2
+    return out + [max_batch]
 
 
 #: cache leaves that hold a recurrent state (the rest hold KV rows)

@@ -268,3 +268,108 @@ def test_decode_program_reads_the_cache_in_place():
         if e.primitive.name == "convert_element_type" \
                 and e.params["new_dtype"] == jnp.float32:
             assert e.invars[0].aval.shape not in cache_shapes, e
+
+
+# --------------------------------------------------------------------------
+# Decode over a batch prefix: a step of b rows serves cache rows 0..b-1
+# --------------------------------------------------------------------------
+
+_decode = jax.jit(model.decode_step, static_argnums=0)
+_forward = jax.jit(model.forward, static_argnums=0,
+                   static_argnames=("return_cache",))
+
+
+def _assert_prefix_step(old, new_p, new_f, lg_p, lg_f, b):
+    """A step of b rows: logits and rows 0..b-1 as the full-batch step's,
+    rows b.. of every leaf bit-identical to before the step."""
+    np.testing.assert_allclose(np.asarray(lg_p), np.asarray(lg_f[:b]),
+                               atol=1e-5, rtol=1e-5)
+    # every leaf is (layers, rows, ...)
+    for o, p, f in zip(*map(jax.tree.leaves, (old, new_p, new_f))):
+        assert p.shape == o.shape
+        np.testing.assert_array_equal(np.asarray(p[:, b:]),
+                                      np.asarray(o[:, b:]))
+        np.testing.assert_allclose(np.asarray(p[:, :b], np.float32),
+                                   np.asarray(f[:, :b], np.float32),
+                                   atol=1e-5, rtol=1e-5)
+
+
+#: live slots, rows decoded, and whether slot 0 is reused after a longer
+#: request, so that its cache holds a stale row at the position decoded
+PREFIX_CASES = {
+    "prefix": ((0, 1), 2, False),
+    "dead_row_inside": ((0, 2), 3, False),
+    "stale_row_at_cur_pos": ((0, 1), 2, True),
+    "full_batch": ((0, 1, 2, 3), 4, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFIX_CASES))
+@pytest.mark.parametrize("name", ["starcoder2-7b", "zamba2-7b",
+                                  "mamba2-370m"])
+def test_a_prefix_decode_serves_its_rows_and_leaves_the_rest(built, name,
+                                                              case):
+    """Four slots, each prefilled with its own prompt; the live ones
+    decode their next token, a dead one token 0 at position 0, as the
+    engine launches them. A step over the first b rows gives the logits
+    and new rows of the step over all four, leaves rows b.. of every
+    cache leaf as they were, and its live rows' logits are the full
+    forward's."""
+    live, b, reused = PREFIX_CASES[case]
+    cfg, params = built(name, dtype="float32")
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, n) for n in (5, 3, 6, 4)]
+    nxt = rng.integers(1, cfg.vocab_size, 4)
+
+    def prefill(tokens):
+        return _forward(cfg, params, {"tokens": jnp.asarray(
+            tokens, jnp.int32)[None]}, return_cache=True)
+
+    cache = model.init_decode_cache(cfg, 4, 16)
+    if reused:                 # slot 0 served 8 tokens and decoded past them
+        cache = _write_slot(cache, prefill(
+            rng.integers(1, cfg.vocab_size, 8))[1], jnp.int32(0))
+        for p in range(8, 11):
+            _, cache = _decode(cfg, params, jnp.ones((1, 1), jnp.int32),
+                               cache, jnp.asarray([p], jnp.int32))
+    for slot, prompt in enumerate(prompts):
+        cache = _write_slot(cache, prefill(prompt)[1], jnp.int32(slot))
+    if reused and cfg.family != "ssm":
+        attn = cache["attn" if cfg.family == "hybrid" else "dense"]
+        cur = len(prompts[0])
+        assert int(attn["pos"][0, 0, cur]) == cur
+    toks = np.zeros((4, 1), np.int32)
+    pos = np.zeros((4,), np.int32)
+    for slot in live:
+        toks[slot, 0], pos[slot] = nxt[slot], len(prompts[slot])
+    toks, pos = jnp.asarray(toks), jnp.asarray(pos)
+    lg_p, new_p = _decode(cfg, params, toks[:b], cache, pos[:b])
+    lg_f, new_f = _decode(cfg, params, toks, cache, pos)
+    assert lg_p.shape[0] == b
+    _assert_prefix_step(cache, new_p, new_f, lg_p, lg_f, b)
+    for slot in live:
+        full = prefill(np.append(prompts[slot], nxt[slot]))[0]
+        err = float(jnp.max(jnp.abs(lg_p[slot, 0] - full[0, -1])))
+        assert err < 1e-3, (slot, err)
+
+
+@pytest.mark.parametrize("name", ALL_ARCHS)
+def test_a_prefix_decode_matches_the_full_batch_in_every_family(built,
+                                                                name):
+    """Every family's cache (MLA's latent, the audio decoder's self and
+    cross caches, a vision prefix) is read and written only in the
+    prefix's rows."""
+    cfg, params = built(name, dtype="float32", capacity_factor=8.0)
+    S, B, b = 10, 3, 2
+    batch = model.make_inputs(cfg, B, S, key=jax.random.PRNGKey(9))
+    ntok = batch["tokens"].shape[1]
+    pre = dict(batch, tokens=batch["tokens"][:, :ntok - 1])
+    _, pcache, _ = _forward(cfg, params, pre, return_cache=True)
+    off = batch["patches"].shape[1] if cfg.family == "vlm" else 0
+    cache = model.merge_prefill_cache(
+        model.init_decode_cache(cfg, B, ntok + off + 4), pcache)
+    toks = batch["tokens"][:, ntok - 1:ntok]
+    cur = jnp.full((B,), ntok - 1 + off, jnp.int32)
+    lg_p, new_p = _decode(cfg, params, toks[:b], cache, cur[:b])
+    lg_f, new_f = _decode(cfg, params, toks, cache, cur)
+    _assert_prefix_step(cache, new_p, new_f, lg_p, lg_f, b)

@@ -99,6 +99,10 @@ def test_the_engine_spans_nest_as_its_tree_and_match_its_counters(
     assert {r["args"]["engine"] for r in steps} == {eng.index}
     rows = [r["args"]["rows"] for r in recs if r["name"] == "engine.decode"]
     assert max(rows) == 2 and min(rows) >= 1
+    decodes = [r["args"] for r in recs if r["name"] == "engine.decode"]
+    assert all(a["rows"] <= a["batch"] and a["batch"] in eng.ladder
+               for a in decodes)
+    assert sum(a["batch"] for a in decodes) == eng.decode_rows_launched
     prefills = [r for r in recs if r["name"] == "engine.prefill"]
     assert sorted(r["args"]["rid"] for r in prefills) == list(range(5))
     assert all(r["args"]["tokens"] in (4, 6) for r in prefills)
