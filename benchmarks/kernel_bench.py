@@ -1,7 +1,7 @@
 """Instance-level kernel micro-bench (CPU interpret mode): us/call +
 allclose check vs the jnp oracle.  Interpret-mode timings are NOT TPU
-performance — the roofline story lives in EXPERIMENTS.md; this verifies
-the harness plumbing and correctness at bench shapes."""
+performance — the chip's rooflines are measured by bench/run.py; this
+verifies the harness plumbing and correctness at bench shapes."""
 from __future__ import annotations
 
 import time

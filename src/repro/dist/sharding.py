@@ -22,6 +22,8 @@ from typing import Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.configs.base import ModelConfig, ShapeConfig
+
 Axis = Optional[str]
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -140,9 +142,34 @@ SERVE_RULES = ShardingRules({
 })
 
 # Long-context decode: context-parallel KV over data (callers override
-# batch/kv_seq per shape; see launch/dryrun.rules_for).
+# batch/kv_seq per shape; see rules_for).
 LONG_CTX_RULES = ShardingRules({**SERVE_RULES, "batch": None,
                                 "kv_seq": "data"})
+
+
+def rules_for(cfg: ModelConfig, shape: ShapeConfig,
+              model_axis: int = 16) -> ShardingRules:
+    """The rules for one (architecture, input shape) on a mesh whose
+    model axis has ``model_axis`` devices."""
+    if shape.mode == "train":
+        base = TRAIN_RULES
+    elif shape.name == "long_500k":
+        base = ShardingRules({**LONG_CTX_RULES, "batch": None,
+                              "kv_seq": ("pod", "data")})
+    else:
+        base = SERVE_RULES
+    rules = ShardingRules(base)
+    # kv heads that don't divide the model axis: shard head_dim instead of
+    # padding the KV cache 4-16x (GSPMD would pad uneven head sharding)
+    if (cfg.num_kv_heads and cfg.num_kv_heads % model_axis != 0
+            and not cfg.use_mla):
+        rules["kv_heads"] = None
+        rules["head_dim"] = "model"
+    if cfg.num_heads and cfg.num_heads % model_axis != 0:
+        rules["heads"] = None
+    if cfg.num_experts and cfg.num_experts % model_axis != 0:
+        rules["expert"] = "data"
+    return rules
 
 
 def named_sharding_tree(axes_tree, mesh, rules: ShardingRules):

@@ -1,17 +1,12 @@
-"""HLO-text analysis utilities + TPU v5e hardware constants.
+"""HLO-text analysis utilities.
 
-Separate from dryrun.py so tests and benchmarks can import it without
-triggering dryrun's 512-device XLA_FLAGS override.
+Separate from dryrun.py so tests can import it without triggering
+dryrun's 512-device XLA_FLAGS override.
 """
 from __future__ import annotations
 
 import re
 from typing import Dict
-
-# TPU v5e hardware constants (per chip)
-PEAK_FLOPS = 197e12      # bf16
-HBM_BW = 819e9           # bytes/s
-ICI_BW = 50e9            # bytes/s per link
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "u64": 8, "s64": 8,
                 "u32": 4, "s32": 4, "u16": 2, "s16": 2, "u8": 1, "s8": 1,

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import P, shard
-from repro.models import flags
 from repro.models.layers import apply_norm, apply_rope, dense_init, init_norm
 
 NEG_INF = -1e30
@@ -128,23 +127,12 @@ def _attend(q, k, v, mask, scale):
     B, S, H, hd = q.shape
     Hkv = k.shape[2]
     g = H // Hkv
-    qg = q.reshape(B, S, Hkv, g, hd)
-    if flags.ATTN_BF16_STREAM:
-        # bf16 operands, fp32 accumulation: halves K/V HBM traffic and
-        # skips the fp32 materialization (see EXPERIMENTS.md §Perf)
-        scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
-                            preferred_element_type=jnp.float32) * scale
-        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-        out = jnp.einsum("bkgst,btkd->bskgd", w, v,
-                         preferred_element_type=jnp.float32)
-    else:
-        qg = qg.astype(jnp.float32)
-        scores = jnp.einsum("bskgd,btkd->bkgst", qg,
-                            k.astype(jnp.float32)) * scale
-        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
-        w = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgst,btkd->bskgd", w, v.astype(jnp.float32))
+    qg = q.reshape(B, S, Hkv, g, hd).astype(jnp.float32)
+    scores = jnp.einsum("bskgd,btkd->bkgst", qg,
+                        k.astype(jnp.float32)) * scale
+    scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+    w = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgst,btkd->bskgd", w, v.astype(jnp.float32))
     return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
@@ -157,8 +145,6 @@ def blockwise_attention(q, k, v, q_positions, k_positions, *,
     k slots with position < 0 are masked out.
     """
     B, S, H, hd = q.shape
-    if flags.PROBE_BLOCK_Q:
-        block_q = flags.PROBE_BLOCK_Q
     bq = min(block_q, S)
     pad = (-S) % bq
     if pad:
@@ -177,8 +163,7 @@ def blockwise_attention(q, k, v, q_positions, k_positions, *,
             mask &= (pi[:, :, None] - k_positions[:, None, :]) < window
         return None, _attend(qi, k, v, mask, scale)
 
-    _, out = jax.lax.scan(step, None, (qb, pb),
-                          unroll=flags.scan_unroll())
+    _, out = jax.lax.scan(step, None, (qb, pb))
     out = out.transpose(1, 0, 2, 3, 4).reshape(B, nblk * bq, H, v.shape[-1])
     return out[:, :S]
 
@@ -320,15 +305,14 @@ def write_decode_rows(cache: Dict, rows: Dict, cur_pos, *,
     """Write each layer's new-token rows into a stacked decode cache.
 
     cache leaves are (L, Bc, W, ...); rows hold the same keys but "pos",
-    each (L, B, ...) in the cache's dtype, for cache rows 0..B-1 (B <= Bc;
-    B == Bc under ``flags.WHERE_CACHE_UPDATE``).  Row b goes to slot
-    cur_pos[b] % W of every layer, and pos there becomes cur_pos[b];
-    nothing else changes.  With the cache donated the update is in place:
-    one scatter over layer, row and slot, so the window it writes is the
-    cache's minor dimensions.  ``per_slot`` writes instead with one
-    dynamic-update-slice per row b, which stays in place whatever the
-    cache's device layout: a TPU lays a cache with head_dim 224 out with
-    the positions minor, and there a scatter re-lays the whole cache."""
+    each (L, B, ...) in the cache's dtype, for cache rows 0..B-1 (B <= Bc).
+    Row b goes to slot cur_pos[b] % W of every layer, and pos there
+    becomes cur_pos[b]; nothing else changes.  With the cache donated the
+    update is in place: one scatter over layer, row and slot, so the window
+    it writes is the cache's minor dimensions.  ``per_slot`` writes instead
+    with one dynamic-update-slice per row b, which stays in place whatever
+    the cache's device layout: a TPU lays a cache with head_dim 224 out
+    with the positions minor, and there a scatter re-lays the whole cache."""
     L, _, W = cache["pos"].shape
     B = cur_pos.shape[0]
     slot = jnp.mod(cur_pos, W)
@@ -341,13 +325,6 @@ def write_decode_rows(cache: Dict, rows: Dict, cur_pos, *,
                 c = jax.lax.dynamic_update_slice(
                     c, r[:, b:b + 1, None], start)
             return c
-    elif flags.WHERE_CACHE_UPDATE:
-        sel = (jnp.arange(W, dtype=jnp.int32)[None, :]
-               == slot[:, None])                         # (B, W)
-
-        def put(c, r):
-            s = sel.reshape((1, B, W) + (1,) * (c.ndim - 3))
-            return jnp.where(s, r[:, :, None], c)
     else:
         lidx = jnp.arange(L)[:, None]
         bidx = jnp.arange(B)[None, :]

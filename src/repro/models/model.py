@@ -157,25 +157,6 @@ def init_decode_cache(cfg: ModelConfig, batch: int, max_seq: int,
     return out
 
 
-def merge_prefill_cache(decode_cache, prefill_cache):
-    """Write a prefill-produced cache into (larger) decode-cache slots.
-
-    Leaves with identical shapes are replaced; leaves differing along one
-    axis (the sequence axis) are written at offset 0 of that axis.
-    """
-    def merge(dst, src):
-        src = src.astype(dst.dtype)
-        if dst.shape == src.shape:
-            return src
-        diff = [i for i, (a, b) in enumerate(zip(dst.shape, src.shape))
-                if a != b]
-        assert len(diff) == 1, (dst.shape, src.shape)
-        idx = tuple(0 for _ in dst.shape)
-        return jax.lax.dynamic_update_slice(dst, src, idx)
-
-    return jax.tree.map(merge, decode_cache, prefill_cache)
-
-
 def cache_logical_axes(cache) -> Any:
     """Map a decode-cache pytree to logical axis tuples (by leaf name/rank)."""
     def walk(path, leaf):

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import P, shard
-from repro.models import flags
 from repro.models.layers import apply_mlp, dense_init, init_mlp
 
 
@@ -64,7 +63,7 @@ def apply_moe(params, x, cfg: ModelConfig, decode: bool = False):
     gates, eidx = jax.lax.top_k(probs, K)                        # (T, K)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    if decode and not (flags.MOE_DECODE_DISPATCH and T * K >= E):
+    if decode:
         y = _gather_experts(params, xt, gates, eidx, cfg)
         if cfg.num_shared_experts:
             y = y + apply_mlp(params["shared"], xt[:, None, :], cfg)[:, 0, :]

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import P, shard
-from repro.models import flags
 from repro.models.layers import dense_init
 
 CONV_WIDTH = 4
@@ -139,8 +138,7 @@ def ssd_chunked(x, dt, A, Bm, Cm, chunk: int,
             new = carry * dec[:, :, None, None] + st
             return new, carry
         final, prev_states = jax.lax.scan(
-            step, s0, (states.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)),
-            unroll=flags.scan_unroll())
+            step, s0, (states.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)))
         prev_states = prev_states.swapaxes(0, 1)              # (b,c,h,p,n)
 
     # ---- chunk-start contribution -------------------------------------------

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import P, shard
 from repro.models import attention as attn
-from repro.models import flags
 from repro.models import moe as moe_mod
 from repro.models import ssm as ssm_mod
 from repro.models.layers import (apply_mlp, apply_norm, dense_init,
@@ -126,8 +125,7 @@ def _ssm_scan(layers, x, cfg, *, t=None, return_cache=False,
     if remat:
         step = jax.checkpoint(
             step, policy=jax.checkpoint_policies.nothing_saveable)
-    (x, _), caches = jax.lax.scan(step, (x, t), layers,
-                                  unroll=flags.scan_unroll())
+    (x, _), caches = jax.lax.scan(step, (x, t), layers)
     return x, (caches if return_cache else None)
 
 
@@ -151,8 +149,7 @@ def _ssm_scan_decode(layers, x, cfg, state, *, t=None):
 
     n = jax.tree.leaves(layers)[0].shape[0]
     (x, _, state), _ = jax.lax.scan(
-        step, (x, t, state), (layers, jnp.arange(n)),
-        unroll=flags.scan_unroll())
+        step, (x, t, state), (layers, jnp.arange(n)))
     return x, state
 
 
@@ -242,15 +239,13 @@ def _scan_stack(layer_params, x, fn, caches=None, remat: bool = False):
         def step(carry, lp):
             y, cache, aux = fn(lp, carry)
             return y, (cache, aux)
-        x, (cache_stack, aux) = jax.lax.scan(step, x, layer_params,
-                                             unroll=flags.scan_unroll())
+        x, (cache_stack, aux) = jax.lax.scan(step, x, layer_params)
     else:
         def step(carry, inp):
             lp, c = inp
             y, cache, aux = fn(lp, carry, c)
             return y, (cache, aux)
-        x, (cache_stack, aux) = jax.lax.scan(step, x, (layer_params, caches),
-                                             unroll=flags.scan_unroll())
+        x, (cache_stack, aux) = jax.lax.scan(step, x, (layer_params, caches))
     return x, cache_stack, aux
 
 

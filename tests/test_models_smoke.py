@@ -83,7 +83,7 @@ def test_decode_matches_full_forward(name):
     _, pcache, _ = model.forward(cfg, params, pre, return_cache=True)
     off = batch["patches"].shape[1] if cfg.family == "vlm" else 0
     dcache = model.init_decode_cache(cfg, 2, ntok + off + 4)
-    dcache = model.merge_prefill_cache(dcache, pcache)
+    dcache = _write_slot(dcache, pcache, jnp.int32(0))
     cur = jnp.full((2,), ntok - 1 + off, jnp.int32)
     lg, _ = model.decode_step(cfg, params, batch["tokens"][:, ntok - 1:ntok],
                               dcache, cur)
@@ -366,8 +366,8 @@ def test_a_prefix_decode_matches_the_full_batch_in_every_family(built,
     pre = dict(batch, tokens=batch["tokens"][:, :ntok - 1])
     _, pcache, _ = _forward(cfg, params, pre, return_cache=True)
     off = batch["patches"].shape[1] if cfg.family == "vlm" else 0
-    cache = model.merge_prefill_cache(
-        model.init_decode_cache(cfg, B, ntok + off + 4), pcache)
+    cache = _write_slot(model.init_decode_cache(cfg, B, ntok + off + 4),
+                        pcache, jnp.int32(0))
     toks = batch["tokens"][:, ntok - 1:ntok]
     cur = jnp.full((B,), ntok - 1 + off, jnp.int32)
     lg_p, new_p = _decode(cfg, params, toks[:b], cache, cur[:b])
