@@ -21,11 +21,29 @@ which a profiler session puts on the device trace's clock::
           engine.prefill.pull     first-token argmax and pull (waits for it)
           engine.slot_write       slot-write launch; args: state_bytes,
                                   kv_bytes (what the write moves)
-      engine.decode          args: rows (live), batch (rows launched)
-        engine.decode.inputs      token and position build and uploads
+      engine.decode          one per launch; args: rows (live), batch (rows
+                             launched), ahead (1: the previous decode's
+                             tokens were still on the device)
+        engine.decode.inputs      position and fresh-token build and upload
         engine.decode.launch      decode launch
-        engine.decode.pull        argmax and pull (waits for the decode)
-        engine.decode.emit        tokens appended, slots finished
+        engine.decode.pull        the previous decode's tokens (waits for it)
+        engine.decode.emit        its tokens appended, its requests finished
+      engine.drain           nothing left to launch: the last decode's
+                             tokens pulled and emitted
+
+The decode is a one-deep pipeline. Its program takes the greedy argmax
+itself and keeps the tokens on the device: a step launches decode k, whose
+input tokens are decode k-1's output, and only then pulls and emits decode
+k-1's tokens, so the device runs decode k while the host emits, returns to
+its caller and builds decode k+1's inputs. The host needs no token to
+launch: a request stops by length alone, so which rows are live, and at
+which positions, is known before the tokens are. A slot is free for
+admission once its request's last decode is launched (the slot write runs
+after that decode on the device: the cache is donated through both); the
+request is finished (``done_step``) in the step that emits its last token,
+and ``has_work`` holds while a decode's tokens are unpulled.
+``decodes_ahead`` counts the decodes launched with the previous one's
+tokens unpulled.
 
 Every request carries host timestamps (``time.perf_counter_ns``), always
 on: ``submit_ns``, ``admit_ns`` (its prefill starts) and one ``token_ns``
@@ -98,7 +116,9 @@ class ServeRequest:
 class _Slot:
     req: Optional[ServeRequest] = None
     pos: int = 0                      # next position to write
-    remaining: int = 0
+    remaining: int = 0                # decodes still to launch
+    fresh: int = -1                   # the prefill's token, which the next
+                                      # decode takes (-1: the last decode's)
 
 
 class ServingEngine:
@@ -126,26 +146,37 @@ class ServingEngine:
         self.decode_count = 0
         #: rows the decodes launched, live or dead (the ``batch`` args)
         self.decode_rows_launched = 0
+        #: decodes launched while the previous decode's tokens were still
+        #: on the device (the ``ahead`` args)
+        self.decodes_ahead = 0
         self.ladder = decode_ladder(max_batch)
+        #: the last launched decode's tokens, one row per slot, on the device
+        self._tokens = jnp.zeros((max_batch,), jnp.int32)
+        #: the launched decode whose tokens are not pulled yet: its token
+        #: buffer and (slot, request, last token?) of each live row
+        self._pending: Optional[Tuple[jax.Array, List[Tuple[
+            int, ServeRequest, bool]]]] = None
 
         self._prefill = jax.jit(_prefill_last, static_argnums=0)
         # the decode cache is donated into every step and every slot
-        # write, so one copy of it is live on the device, not two
+        # write, so one copy of it is live on the device, not two. The
+        # decode is the engine's one lambda: its module, jit__lambda, is
+        # how a profile tells it from the other programs
         self._decode = jax.jit(
-            lambda p, toks, cache, pos: model_mod.decode_step(
-                cfg, p, toks, cache, pos), donate_argnums=(2,))
+            lambda p, toks, rows, cache: _greedy_decode(
+                cfg, p, toks, rows, cache), donate_argnums=(3,))
         self._write = jax.jit(_write_slot, donate_argnums=(0,))
         self._build_decodes()
 
     def _build_decodes(self) -> None:
-        """One decode and its token pull per rung on the fresh cache, whose
-        rows are all dead (an admission rewrites a slot's rows whole), so
-        that no step builds or loads a program for them."""
+        """One decode per rung on the fresh cache, whose rows are all dead
+        (an admission rewrites a slot's rows whole), so that no step builds
+        or loads a program for them."""
         for b in self.ladder:
-            logits, self.cache = self._decode(
-                self.params, jnp.asarray(np.zeros((b, 1), np.int32)),
-                self.cache, jnp.asarray(np.zeros((b,), np.int32)))
-            _next_tokens(logits)
+            rows = np.zeros((2, b), np.int32)
+            toks, self.cache = self._decode(self.params, self._tokens,
+                                            jnp.asarray(rows), self.cache)
+            np.asarray(toks)
 
     # ---------------------------------------------------------------- intake
     def submit(self, req: ServeRequest) -> None:
@@ -158,7 +189,8 @@ class ServingEngine:
 
     @property
     def has_work(self) -> bool:
-        return bool(self.queue) or self.active > 0
+        return bool(self.queue) or self.active > 0 \
+            or self._pending is not None
 
     # ----------------------------------------------------------------- steps
     def _admit(self) -> None:
@@ -201,33 +233,34 @@ class ServingEngine:
                       if self.cfg.family == "vlm" else 0)
             with span("engine.slot_write", **cache_bytes(pcache)):
                 self.cache = self._write(self.cache, pcache, jnp.int32(slot))
+            req.tokens.append(next_tok)
+            req.ttft_step = self.step_count
+            if req.max_new_tokens <= 1:
+                req.done_step = self.step_count
+                return
             st = self.slots[slot]
             st.req = req
             st.pos = S + offset
             st.remaining = req.max_new_tokens - 1
-            req.tokens.append(next_tok)
-            req.ttft_step = self.step_count
-            if st.remaining <= 0:
-                self._finish(slot)
-
-    def _finish(self, slot: int) -> None:
-        st = self.slots[slot]
-        st.req.done_step = self.step_count
-        st.req = None
-        st.pos = 0
-        st.remaining = 0
+            st.fresh = next_tok
 
     def step(self) -> None:
-        """One engine iteration: admit waiting requests, decode one token
-        for every active slot."""
+        """One engine iteration: admit waiting requests, launch a decode of
+        one token for every active slot, then emit the previous decode's
+        tokens; with no slot active, only emit them."""
         self.step_count += 1
         with step_span("engine.step", self.step_count, engine=self.index):
             self._admit()
             rows = self.active
             if rows:
                 b = self.decode_batch()
-                with span("engine.decode", rows=rows, batch=b):
+                ahead = int(self._pending is not None)
+                with span("engine.decode", rows=rows, batch=b, ahead=ahead):
                     self._decode_one(b)
+            elif self._pending is not None:
+                with span("engine.drain"):
+                    pending, self._pending = self._pending, None
+                    self._emit(*_pull(pending))
 
     def decode_batch(self) -> int:
         """Rows the next decode launches: the smallest rung that holds the
@@ -236,44 +269,73 @@ class ServingEngine:
         return next(b for b in self.ladder if b > top)
 
     def _decode_one(self, b: int) -> None:
-        """Decode one token for every active slot, all among the first
-        ``b``."""
+        """Launch a decode of one token for every active slot, all among
+        the first ``b``; then pull and emit the previous decode's tokens.
+        A slot whose last decode this is frees for the next admission."""
         with span("engine.decode.inputs"):
-            toks = np.zeros((b, 1), np.int32)
-            pos = np.zeros((b,), np.int32)
-            for i, s in enumerate(self.slots[:b]):
-                if s.req is not None:
-                    toks[i, 0] = s.req.tokens[-1]
-                    pos[i] = s.pos
-            toks, pos = jnp.asarray(toks), jnp.asarray(pos)
-        with span("engine.decode.launch"):
-            logits, self.cache = self._decode(self.params, toks, self.cache,
-                                              pos)
-            self.decode_count += 1
-            self.decode_rows_launched += b
-        with span("engine.decode.pull"):
-            nxt = _next_tokens(logits)
-            t = time.perf_counter_ns()
-        with span("engine.decode.emit"):
+            rows = np.zeros((2, b), np.int32)     # positions, fresh tokens
+            rows[1] = -1
+            launched = []
             for i, s in enumerate(self.slots[:b]):
                 if s.req is None:
                     continue
-                s.req.tokens.append(int(nxt[i]))
-                s.req.token_ns.append(t)
+                rows[:, i] = s.pos, s.fresh
                 s.pos += 1
                 s.remaining -= 1
-                if s.remaining <= 0 or s.pos >= self.max_seq - 1:
-                    self._finish(i)
+                s.fresh = -1
+                last = s.remaining <= 0 or s.pos >= self.max_seq - 1
+                launched.append((i, s.req, last))
+                if last:
+                    self.slots[i] = _Slot()
+            rows = jnp.asarray(rows)
+        with span("engine.decode.launch"):
+            self._tokens, self.cache = self._decode(
+                self.params, self._tokens, rows, self.cache)
+            self.decode_count += 1
+            self.decode_rows_launched += b
+        previous, self._pending = self._pending, (self._tokens, launched)
+        if previous is None:
+            return
+        self.decodes_ahead += 1
+        with span("engine.decode.pull"):
+            pulled = _pull(previous)
+        with span("engine.decode.emit"):
+            self._emit(*pulled)
+
+    def _emit(self, toks: np.ndarray, rows, t: int) -> None:
+        """Append a pulled decode's tokens; finish the requests whose last
+        token it made."""
+        for i, req, last in rows:
+            req.tokens.append(int(toks[i]))
+            req.token_ns.append(t)
+            if last:
+                req.done_step = self.step_count
 
     def run(self, max_steps: int = 10_000) -> None:
         while self.has_work and self.step_count < max_steps:
             self.step()
 
 
-def _next_tokens(logits) -> np.ndarray:
-    """Each decoded row's greedy token, on the host (waits for the
-    decode)."""
-    return np.asarray(jnp.argmax(logits[:, 0, :], axis=-1))
+def _pull(pending):
+    """(its tokens on the host, its rows, when they arrived) of a launched
+    decode (waits for it)."""
+    toks, rows = pending
+    return np.asarray(toks), rows, time.perf_counter_ns()
+
+
+def _greedy_decode(cfg: ModelConfig, params, toks, rows, cache):
+    """One greedy decode of the first ``b`` slots: (the slots' next tokens
+    (max_batch,), the cache). ``toks`` (max_batch,) holds the last decode's
+    tokens; ``rows`` (2, b) each launched row's position and its fresh
+    token, the prefill's, which it takes in place of the last decode's
+    (-1: none). Rows from ``b`` on keep their tokens."""
+    pos, fresh = rows[0], rows[1]
+    b = pos.shape[0]
+    tok = jnp.where(fresh >= 0, fresh, toks[:b])
+    logits, cache = model_mod.decode_step(cfg, params, tok[:, None], cache,
+                                          pos)
+    nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
+    return toks.at[:b].set(nxt), cache
 
 
 def decode_ladder(max_batch: int) -> List[int]:

@@ -25,7 +25,8 @@ TREE = {"engine.step": None,
         "engine.decode.inputs": "engine.decode",
         "engine.decode.launch": "engine.decode",
         "engine.decode.pull": "engine.decode",
-        "engine.decode.emit": "engine.decode"}
+        "engine.decode.emit": "engine.decode",
+        "engine.drain": "engine.step"}
 
 
 @pytest.fixture(scope="module")
@@ -103,6 +104,7 @@ def test_the_engine_spans_nest_as_its_tree_and_match_its_counters(
     assert all(a["rows"] <= a["batch"] and a["batch"] in eng.ladder
                for a in decodes)
     assert sum(a["batch"] for a in decodes) == eng.decode_rows_launched
+    assert sum(a["ahead"] for a in decodes) == eng.decodes_ahead > 0
     prefills = [r for r in recs if r["name"] == "engine.prefill"]
     assert sorted(r["args"]["rid"] for r in prefills) == list(range(5))
     assert all(r["args"]["tokens"] in (4, 6) for r in prefills)
@@ -138,3 +140,35 @@ def test_served_tokens_are_the_same_with_the_profiler_on_and_off(
     eng, on = serve(tiny, tmp_path)
     assert [r.tokens for r in on] == [r.tokens for r in off]
     assert len(telemetry.spans()) > 0
+
+
+def test_each_decode_launches_before_the_previous_ones_tokens_are_pulled(
+        tiny, clean, tmp_path):
+    """In every step with a decode in flight the next decode's launch
+    starts before that step's pull; a step with nothing left to launch
+    pulls in ``engine.drain``, and the run ends with one."""
+    eng, reqs = serve(tiny, tmp_path)
+    recs = telemetry.spans()
+    kids = {}
+    for r in recs:
+        kids.setdefault(r["parent"], []).append(r)
+    in_flight = False
+    for st in (r for r in recs if r["name"] == "engine.step"):
+        names = {c["name"]: c for c in kids.get(st["index"], [])}
+        if "engine.decode" in names:
+            dec = names["engine.decode"]
+            parts = {c["name"]: c for c in kids[dec["index"]]}
+            assert dec["args"]["ahead"] == int(in_flight)
+            if in_flight:
+                assert parts["engine.decode.launch"]["end_ns"] <= \
+                    parts["engine.decode.pull"]["start_ns"]
+                assert parts["engine.decode.pull"]["end_ns"] <= \
+                    parts["engine.decode.emit"]["start_ns"]
+            else:
+                assert "engine.decode.pull" not in parts
+            in_flight = True
+        elif in_flight:
+            assert "engine.drain" in names
+            in_flight = False
+    assert not in_flight and names.get("engine.drain") is not None
+    assert all(r.done_step is not None for r in reqs)
